@@ -31,6 +31,10 @@ def main() -> None:
     args = ap.parse_args()
     import importlib
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     failures = 0
     for name, module in SECTIONS:
         if args.only and name != args.only:

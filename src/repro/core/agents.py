@@ -70,6 +70,12 @@ class ArchetypeContext(NamedTuple):
     num_levels: int     # L — static price-grid width
 
 
+def _select_bool(mask, a, b):
+    """``where(mask, a, b)`` over boolean operands, as boolean algebra:
+    the same values, and Mosaic cannot select between boolean vectors."""
+    return (mask & a) | (~mask & b)
+
+
 # type_id -> (name, fn(ctx) -> (side_buy, price_f)); ids match config constants.
 _ARCHETYPES: Dict[int, Tuple[str, Callable]] = {}
 
@@ -101,7 +107,8 @@ def _momentum(ctx: ArchetypeContext):
     xp, f32 = ctx.xp, ctx.xp.float32
     ret = xp.sign(ctx.mid - ctx.prev_mid)  # float32[M, 1]
     ret = ret + xp.zeros_like(ctx.u_side)  # broadcast [M, A]
-    side_buy = xp.where(ret != f32(0.0), ret > f32(0.0), ctx.u_side < f32(0.5))
+    side_buy = _select_bool(ret != f32(0.0), ret > f32(0.0),
+                            ctx.u_side < f32(0.5))
     price_f = ctx.mid + xp.where(side_buy, f32(1.0), f32(-1.0))
     return side_buy, price_f
 
@@ -128,7 +135,8 @@ def _fundamentalist(ctx: ArchetypeContext):
     fundamental = xp.asarray(ctx.params.fundamental, dtype=f32)
     dev = fundamental - ctx.mid               # float32[M, 1]
     dev = dev + xp.zeros_like(ctx.u_side)     # broadcast [M, A]
-    side_buy = xp.where(dev != f32(0.0), dev > f32(0.0), ctx.u_side < f32(0.5))
+    side_buy = _select_bool(dev != f32(0.0), dev > f32(0.0),
+                            ctx.u_side < f32(0.5))
     jitter = ctx.u_price * f32(2.0) - f32(1.0)
     kappa = xp.asarray(ctx.params.fundamentalist_kappa, dtype=f32)
     price_f = ctx.mid + dev * kappa + jitter
@@ -160,8 +168,8 @@ def _hft(ctx: ArchetypeContext):
     xp, f32 = ctx.xp, ctx.xp.float32
     imb = ctx.imbalance + xp.zeros_like(ctx.u_side)  # broadcast [M, A]
     thr = xp.asarray(ctx.params.hft_threshold, dtype=f32)
-    side_buy = xp.where(xp.abs(imb) > thr, imb > f32(0.0),
-                        ctx.u_side < f32(0.5))
+    side_buy = _select_bool(xp.abs(imb) > thr, imb > f32(0.0),
+                            ctx.u_side < f32(0.5))
     price_f = ctx.mid + xp.where(side_buy, f32(1.0), f32(-1.0))
     return side_buy, price_f
 
@@ -180,7 +188,7 @@ def _informed(ctx: ArchetypeContext):
               & (ctx.step_i < shock_step)) | false_b
     calm_side = ctx.u_side < f32(0.5)
     calm_price = ctx.mid + (ctx.u_price * f32(2.0) - f32(1.0))
-    side_buy = xp.where(window, false_b, calm_side)
+    side_buy = ~window & calm_side
     price_f = xp.where(window, f32(0.0), calm_price)
     return side_buy, price_f
 
@@ -194,7 +202,8 @@ def _arbitrageur(ctx: ArchetypeContext):
     xp, f32 = ctx.xp, ctx.xp.float32
     gap = ctx.peer_mid - ctx.mid              # float32[M, 1]
     gap = gap + xp.zeros_like(ctx.u_side)     # broadcast [M, A]
-    side_buy = xp.where(gap != f32(0.0), gap > f32(0.0), ctx.u_side < f32(0.5))
+    side_buy = _select_bool(gap != f32(0.0), gap > f32(0.0),
+                            ctx.u_side < f32(0.5))
     kappa = xp.asarray(ctx.params.arb_kappa, dtype=f32)
     jitter = ctx.u_price * f32(2.0) - f32(1.0)
     price_f = ctx.mid + gap * kappa + jitter
@@ -323,7 +332,7 @@ def decide(cfg, params: MarketParams, mid, prev_mid, step, market_ids,
         _, fn = _ARCHETYPES[tid]
         s, p = fn(ctx)
         mask = atype == xp.int32(tid)
-        side_buy = xp.where(mask, s | zero_b, side_buy)
+        side_buy = _select_bool(mask, s, side_buy)
         price_f = xp.where(mask, p + zero_f, price_f)
 
     is_maker = atype == MAKER
@@ -347,7 +356,7 @@ def decide(cfg, params: MarketParams, mid, prev_mid, step, market_ids,
         shock_int = xp.asarray(params.shock_intensity, dtype=f32)
         at_shock = (step_i == shock_step) | zero_b
         panic = (u_shock < shock_int) & ~is_maker & at_shock
-        side_buy = xp.where(panic, zero_b, side_buy)
+        side_buy = ~panic & side_buy
         price_f = xp.where(panic, f32(0.0) + zero_f, price_f)
 
     # Round-half-even (identical in NumPy & JAX), prune to the grid (paper

@@ -80,6 +80,8 @@ def clear(total_buy, total_ask, xp, scan="cumsum"):
       total_buy / total_ask: float32[..., L] aggregate resting+incoming books.
       scan: 'cumsum' (XLA native) or 'hillis-steele' (paper-faithful log-depth
         strided scan) — bitwise-identical results for exact-integer books.
+        The Pallas kernels always use 'hillis-steele': Mosaic lowers
+        neither ``cumsum`` nor the ``flip`` of the suffix sum.
 
     Returns dict with p_star int32[...,1], volume float32[...,1],
     new_bid/new_ask float32[...,L], traded_buy/traded_sell float32[...,L].
@@ -93,10 +95,15 @@ def clear(total_buy, total_ask, xp, scan="cumsum"):
         s_cum = prefix_sum(total_ask, xp)
 
     match = xp.minimum(d_cum, s_cum)  # executable volume V(p)
-    # argmax returns the first (lowest-price) maximizer in both NumPy & JAX,
-    # matching the paper's tournament tie-break toward lower ticks.
-    p_star = xp.argmax(match, axis=-1).astype(xp.int32)[..., None]
-    volume = xp.take_along_axis(match, p_star, axis=-1)
+    # p* is the first (lowest-price) maximizer — the paper's tournament
+    # tie-break toward lower ticks — written as two reductions: an argmax
+    # leaves the tie-break to each backend's lowering, and a gather of the
+    # volume at p* does not lower in Mosaic.
+    L = match.shape[-1]
+    volume = xp.max(match, axis=-1, keepdims=True)
+    levels = xp.arange(L, dtype=xp.int32)
+    p_star = xp.min(xp.where(match == volume, levels, xp.int32(L)), axis=-1,
+                    keepdims=True)
 
     # Priority allocation (closed form of paper §IV-C):
     #   demand strictly above p: d_cum[p] - total_buy[p]

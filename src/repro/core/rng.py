@@ -69,9 +69,12 @@ def uniform32(seed, gid, step, channel, xp):
     guarantees the result is strictly below 1.0 (a raw 32-bit conversion can
     round up to 2**32 and yield exactly 1.0, which would overflow the
     integer-quantity draw q = 1 + floor(u * q_max)).
+
+    The top 24 bits fit in int32, so the conversion goes through int32:
+    the same value, and Mosaic has no uint32 -> float32 cast.
     """
     bits = kinetic_hash32(seed, gid, step, channel, xp)
-    hi24 = (bits >> 8).astype(xp.float32)
+    hi24 = (bits >> 8).astype(xp.int32).astype(xp.float32)
     return hi24 * xp.float32(2.0 ** -24)
 
 

@@ -336,8 +336,8 @@ def run_runner_to_result(runner: ChunkRunner, spec) -> SimResult:
 class Engine:
     """Compiled-executable cache + session factory for one backend.
 
-    ``backend_opts`` are backend-specific knobs (``scan=``, ``mb=``,
-    ``interpret=``, ``binning=``, and for the Pallas engines the scaling
+    ``backend_opts`` are backend-specific knobs (``scan=`` and
+    ``binning=`` for the NumPy/JAX engines; ``mb=`` and the scaling
     knobs ``devices=``/``mesh=`` market-axis sharding, ``stats_only=``
     in-kernel statistics, ``autotune=``/``agent_chunk=`` tile selection —
     see ``repro.kernels.ops``) folded into every runner this engine

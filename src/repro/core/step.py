@@ -64,24 +64,72 @@ def bin_orders_onehot(side_buy, price, qty, L, xp, agent_chunk=None):
     VMEM term inside the persistent kernel) by accumulating the contraction
     over static slices of the agent axis. Because every partial sum is an
     exact integer in f32, the result is bitwise-identical for any chunking.
+
+    The quantities carry a unit middle axis (``[M, 1, A] x [M, A, L]``):
+    Mosaic lowers a batched matmul only when the lhs has a non-contracting
+    dimension. Agent slices are cut with plain slices and ``expand_dims``,
+    never mixed indexing, which Mosaic would see as a gather.
     """
     levels = xp.arange(L, dtype=xp.int32)
-    qb = qty * side_buy.astype(xp.float32)
-    qs = qty * (~side_buy).astype(xp.float32)
-    A = price.shape[-1]
-    if not agent_chunk or agent_chunk >= A:
-        onehot = (price[..., None] == levels).astype(xp.float32)  # [M, A, L]
-        return (xp.einsum("ma,mal->ml", qb, onehot),
-                xp.einsum("ma,mal->ml", qs, onehot))
-    M = price.shape[0]
-    buy = xp.zeros((M, L), dtype=xp.float32)
-    sell = xp.zeros((M, L), dtype=xp.float32)
-    for a0 in range(0, A, agent_chunk):
-        sl = slice(a0, min(a0 + agent_chunk, A))
-        onehot = (price[:, sl, None] == levels).astype(xp.float32)
-        buy = buy + xp.einsum("ma,mal->ml", qb[:, sl], onehot)
-        sell = sell + xp.einsum("ma,mal->ml", qs[:, sl], onehot)
+    qb = xp.expand_dims(qty * side_buy.astype(xp.float32), 1)     # [M, 1, A]
+    qs = xp.expand_dims(qty * (~side_buy).astype(xp.float32), 1)
+    price = xp.expand_dims(price, -1)                              # [M, A, 1]
+    A = price.shape[1]
+    step = A if not agent_chunk else min(agent_chunk, A)
+    buy = sell = None
+    for a0 in range(0, A, step):
+        a1 = min(a0 + step, A)
+        onehot = (price[:, a0:a1] == levels).astype(xp.float32)   # [M, Ac, L]
+        b = xp.einsum("mka,mal->mkl", qb[:, :, a0:a1], onehot)[:, 0]
+        s = xp.einsum("mka,mal->mkl", qs[:, :, a0:a1], onehot)[:, 0]
+        buy, sell = (b, s) if buy is None else (buy + b, sell + s)
     return buy, sell
+
+
+def exact_ratio(num, den, xp):
+    """``num / den`` rounded to nearest even — the IEEE f32 quotient — for
+    integer-valued f32 ``|num| <= den < 2**24``, ``den >= 1``.
+
+    The TPU's f32 divider is not correctly rounded (it is off by an ulp on
+    about a third of such quotients), so the hardware quotient serves only
+    as an estimate: the exact integer quotient ``floor(|num| * 2**s / den)``
+    (25 significant bits) is recovered from it with an int32 remainder, and
+    rounded once. Wrapping int32 products are exact here because the true
+    remainder is small. Bitwise equal to ``num / den`` on every backend.
+    """
+    i32 = xp.int32
+    zero, one = i32(0), i32(1)
+    a = xp.abs(num).astype(i32)
+    d = den.astype(i32)
+    # The estimate is m * 2**(b - 150): 24-bit significand m, biased exponent
+    # b. Bit fields, not frexp/ldexp, which compute 2**k through pow.
+    bits = xp.maximum(xp.abs(num) / den, xp.float32(2.0 ** -30)).view(i32)
+    m = (bits & i32(0x7FFFFF)) | i32(0x800000)
+    s = i32(151) - (bits >> i32(23))              # estimate * 2**s = 2m
+    q = m + m
+    # r = |num| * 2**s - q * den, mod 2**32 (a shift of 32 or more is 0).
+    r = xp.where(s > i32(31), zero, a << xp.minimum(s, i32(31))) - q * d
+    k = xp.floor(r.astype(xp.float32) / den).astype(i32)
+    q, r = q + k, r - k * d
+    lo, hi = r < zero, r >= d                         # k may be off by one
+    q = q + hi.astype(i32) - lo.astype(i32)
+    r = r + xp.where(lo, d, zero) - xp.where(hi, d, zero)
+    # Normalize q to 25 bits (the estimate's binade may be one off).
+    big = q >= i32(1 << 25)
+    sticky = big & ((q & one) == one)
+    q, s = xp.where(big, q >> one, q), xp.where(big, s - one, s)
+    small = q < i32(1 << 24)
+    bit = small & (r + r >= d)
+    q = xp.where(small, q + q + bit.astype(i32), q)
+    r = xp.where(small, r + r - xp.where(bit, d, zero), r)
+    s = xp.where(small, s + one, s)
+    # Round the 25th bit away, to nearest even.
+    half = (q & one) == one
+    q = q >> one
+    up = half & ((r != zero) | sticky | ((q & one) == one))
+    scale = ((i32(128) - s) << i32(23)).view(xp.float32)         # 2**(1 - s)
+    out = (q + up.astype(i32)).astype(xp.float32) * scale
+    return xp.where(a == zero, num, xp.where(num < xp.float32(0), -out, out))
 
 
 def apply_scenario_shock(params: MarketParams, bid, step_idx, xp):
@@ -203,13 +251,15 @@ def simulate_step(
     _, _, mid = auction.best_quotes(resting_bid, state.ask, state.last_price, xp)
 
     # Resting-book imbalance for the HFT archetype: exact-integer f32 sums
-    # (book mass stays far below 2^24), one IEEE division — deterministic
-    # and bitwise-identical across backends, chunkings, and shardings.
+    # (book mass stays far below 2^24), one correctly rounded division —
+    # deterministic and bitwise-identical across backends, devices,
+    # chunkings, and shardings.
     sum_bid = xp.sum(resting_bid, axis=-1, keepdims=True)
     sum_ask = xp.sum(state.ask, axis=-1, keepdims=True)
     depth = sum_bid + sum_ask
     safe_depth = xp.where(depth > f32(0.0), depth, f32(1.0))  # no 0/0 (numpy)
-    imbalance = xp.where(depth > f32(0.0), (sum_bid - sum_ask) / safe_depth,
+    imbalance = xp.where(depth > f32(0.0),
+                         exact_ratio(sum_bid - sum_ask, safe_depth, xp),
                          xp.zeros_like(depth))
 
     # Phase 3: agent decisions + order aggregation (lines 8-13)

@@ -20,6 +20,7 @@ chunked accumulation bitwise-identical for any chunk size.
 """
 from __future__ import annotations
 
+import re
 import time
 from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 
@@ -32,9 +33,9 @@ _TUNE_CACHE: Dict[Tuple[str, int, int, int], "TileChoice"] = {}
 #: ops/chaos harness reads these to assert an OOM-shaped sweep fell back.
 _SWEEP_REPORTS: List["SweepReport"] = []
 
-# Substrings identifying an out-of-memory-shaped backend failure. XLA spells
-# device OOM "RESOURCE_EXHAUSTED"; Mosaic VMEM overflows mention VMEM.
-_OOM_MARKERS = ("resource_exhausted", "out of memory", "oom", "vmem")
+# Identifies an out-of-memory-shaped backend failure. XLA spells device OOM
+# "RESOURCE_EXHAUSTED"; Mosaic VMEM overflows mention VMEM.
+_OOM_PATTERN = re.compile(r"resource_exhausted|out of memory|\boom\b|vmem")
 
 
 class SweepReport(NamedTuple):
@@ -42,15 +43,14 @@ class SweepReport(NamedTuple):
 
     key: Tuple                     # the _TUNE_CACHE key that was populated
     winner: "TileChoice"           # the cached choice (fallback when fell_back)
-    fell_back: bool                # True iff every candidate failed
+    fell_back: bool                # True iff every candidate ran out of memory
     tried: Tuple["TileChoice", ...]
-    failures: Tuple[str, ...]      # one "CandRepr: ExcType: msg" per failure
+    failures: Tuple[str, ...]      # one "CandRepr: ExcType: msg" per OOM
 
 
 def is_oom_error(exc: BaseException) -> bool:
     """Heuristic: does this exception look like a device/VMEM OOM?"""
-    text = f"{type(exc).__name__}: {exc}".lower()
-    return any(m in text for m in _OOM_MARKERS)
+    return bool(_OOM_PATTERN.search(f"{type(exc).__name__}: {exc}".lower()))
 
 
 def estimate_vmem_bytes(tile: "TileChoice", num_levels: int,
@@ -143,7 +143,7 @@ def candidate_tiles(num_markets: int, num_agents: int,
 def tune_key(num_levels: int, num_agents: int, chunk: int,
              **context) -> Tuple:
     """Winner cache key: (device-kind, L, A, chunk) plus any ``context``
-    that changes what is being timed (kernel family, scan mode, stats_only,
+    that changes what is being timed (kernel family, stats_only,
     a pinned agent_chunk) — distinct kernel configurations must never share
     a measured winner."""
     import jax
@@ -160,9 +160,11 @@ def autotune_tile(key: Tuple,
     """Measure each candidate once (first compile), cache the winner.
 
     ``time_candidate`` compiles + runs one representative chunk call and
-    returns its wall time; exceptions (e.g. a tile the backend rejects)
-    disqualify the candidate rather than failing the sweep. If every
-    candidate fails, ``fallback`` (the caller's heuristic choice) is used.
+    returns its wall time. An out-of-memory-shaped failure
+    (:func:`is_oom_error`: the tile does not fit) disqualifies the
+    candidate; any other exception, such as a kernel the compiler rejects,
+    propagates. If every candidate runs out of memory, ``fallback`` (the
+    caller's heuristic choice) is used.
     Cached winners are re-padded for the caller's ``num_markets`` — only
     (mb, agent_chunk) is reused across ensemble sizes.
     """
@@ -173,7 +175,9 @@ def autotune_tile(key: Tuple,
         for cand in cands:
             try:
                 t = time_candidate(cand)
-            except Exception as exc:  # a rejected/OOM tile disqualifies itself
+            except Exception as exc:
+                if not is_oom_error(exc):
+                    raise
                 failures.append(f"{cand!r}: {type(exc).__name__}: {exc}")
                 continue
             if t < best_t:

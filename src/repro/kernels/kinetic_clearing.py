@@ -62,10 +62,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # TPU compiler params are optional on CPU/interpret
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import params as params_mod
 from repro.core import stats as stats_mod
@@ -76,6 +73,42 @@ from repro.kernels.autotune import pad_to_multiple
 
 #: Number of per-market parameter operands threaded into the chunk kernels.
 NUM_PARAM_OPERANDS = len(MarketParams._fields)
+
+#: The kernels' clearing scan. Mosaic lowers the log-depth Hillis-Steele
+#: scan; it has no rule for ``cumsum`` or for the ``flip`` of the suffix
+#: sum. Both scans give the same bits on exact-integer books.
+KERNEL_SCAN = "hillis-steele"
+
+
+def pallas_call(body, *, interpret: bool, **kwargs):
+    """``pl.pallas_call`` for the clearing kernels: Mosaic with a parallel
+    market grid, or the interpreter for tests on a host without a TPU.
+
+    Interpret mode is refused on a TPU backend, so no caller can time or
+    ship the interpreter by mistake.
+    """
+    if interpret:
+        if jax.default_backend() == "tpu":
+            raise ValueError("interpret=True on a TPU backend: the clearing "
+                             "kernels lower through Mosaic there")
+    else:
+        kwargs["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("parallel",))
+    return pl.pallas_call(body, interpret=interpret, **kwargs)
+
+
+def write_column(path, s, col):
+    """``path`` with column ``s`` replaced by ``col`` ([mb, 1]): an
+    iota-mask select, since Mosaic cannot lower ``dynamic_update_slice``
+    into a VMEM value."""
+    cols = jax.lax.broadcasted_iota(jnp.int32, path.shape, 1)
+    return jnp.where(cols == s, col, path)
+
+
+def tile_market_ids(mb: int):
+    """Global market ids of grid cell ``program_id(0)``'s rows, [mb, 1]."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, (mb, 1), 0)
+    return pl.program_id(0) * mb + rows
 
 
 def resolve_params(cfg, M: int, params: Optional[MarketParams],
@@ -103,10 +136,9 @@ def _kernel_body(
     bid_ref, ask_ref, last_ref, pmid_ref,
     out_bid_ref, out_ask_ref, out_last_ref, out_pmid_ref,
     price_path_ref, volume_path_ref,
-    *, cfg: MarketConfig, mb: int, scan: str,
+    *, cfg: MarketConfig, mb: int,
 ):
     """Persistent scheduler (paper Alg. 1) for one tile of ``mb`` markets."""
-    i = pl.program_id(0)
     S = cfg.num_steps
 
     # Phase 1: load opening books into VMEM-resident values (Alg.1 lines 2-3).
@@ -115,17 +147,17 @@ def _kernel_body(
     last = last_ref[...]
     pmid = pmid_ref[...]
 
-    market_ids = (i * mb + jnp.arange(mb, dtype=jnp.int32))[:, None]
+    market_ids = tile_market_ids(mb)
 
     def body(s, carry):
         bid, ask, last, pmid, pp, vp = carry
         state = MarketState(bid=bid, ask=ask, last_price=last, prev_mid=pmid)
         # Phases 2-5 (Alg.1 lines 5-22): shared semantics, one-hot binning.
         new_state, out = simulate_step(
-            cfg, state, s, market_ids, jnp, bin_orders=None, scan=scan
+            cfg, state, s, market_ids, jnp, bin_orders=None, scan=KERNEL_SCAN
         )
-        pp = jax.lax.dynamic_update_slice(pp, out.price, (0, s))
-        vp = jax.lax.dynamic_update_slice(vp, out.volume, (0, s))
+        pp = write_column(pp, s, out.price)
+        vp = write_column(vp, s, out.volume)
         return (new_state.bid, new_state.ask, new_state.last_price,
                 new_state.prev_mid, pp, vp)
 
@@ -174,7 +206,7 @@ def _chunk_kernel_body(
     bid_ref, ask_ref, last_ref, pmid_ref, ext_buy_ref, ext_ask_ref,
     peer_ref,
     *refs,
-    cfg, mb: int, chunk: int, scan: str,
+    cfg, mb: int, chunk: int,
     agent_chunk: Optional[int], stats_only: bool,
 ):
     """Session variant of the persistent scheduler: a fixed ``chunk``-length
@@ -236,7 +268,7 @@ def _chunk_kernel_body(
         ea = jnp.where(s == jnp.int32(0), ext_a, zeros_ext)
         new_state, out = simulate_step(
             cfg, state, step0 + s, market_ids, jnp, bin_orders=None,
-            scan=scan, ext_buy=eb, ext_ask=ea, agent_chunk=agent_chunk,
+            scan=KERNEL_SCAN, ext_buy=eb, ext_ask=ea, agent_chunk=agent_chunk,
             params=params, atype=atype, peer_mid=peer_mid,
         )
         # Steps past n_valid are computed but discarded — the carried state
@@ -272,9 +304,9 @@ def _chunk_kernel_body(
             bid, ask, last, pmid, pp, vp, mp = carry
             _, bid, ask, last, pmid, out = advance(s, bid, ask, last, pmid)
             # Caller slices the paths to the first n_valid columns.
-            pp = jax.lax.dynamic_update_slice(pp, out.price, (0, s))
-            vp = jax.lax.dynamic_update_slice(vp, out.volume, (0, s))
-            mp = jax.lax.dynamic_update_slice(mp, out.mid, (0, s))
+            pp = write_column(pp, s, out.price)
+            vp = write_column(vp, s, out.volume)
+            mp = write_column(mp, s, out.mid)
             return bid, ask, last, pmid, pp, vp, mp
 
         pp0 = jnp.zeros((mb, chunk), jnp.float32)
@@ -297,7 +329,7 @@ def kinetic_clearing_chunk(
     bid: jax.Array, ask: jax.Array, last: jax.Array, pmid: jax.Array,
     step0: jax.Array, n_valid: jax.Array,
     ext_buy: jax.Array, ext_ask: jax.Array,
-    *, cfg, chunk: int, mb: int = 8, scan: str = "cumsum",
+    *, cfg, chunk: int, mb: int = 8,
     interpret: bool = False, market_ids: Optional[jax.Array] = None,
     agent_chunk: Optional[int] = None,
     params: Optional[MarketParams] = None,
@@ -362,12 +394,6 @@ def kinetic_clearing_chunk(
     step_spec = pl.BlockSpec((1, 1), lambda i: (0, 0))
     path_spec = pl.BlockSpec((mb, chunk), lambda i: (i, 0))
 
-    kwargs = {}
-    if pltpu is not None and not interpret:
-        kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel",),
-        )
-
     state_shapes = (
         jax.ShapeDtypeStruct((m_padded, L), jnp.float32),
         jax.ShapeDtypeStruct((m_padded, L), jnp.float32),
@@ -399,16 +425,14 @@ def kinetic_clearing_chunk(
         out_shapes = state_shapes + (
             jax.ShapeDtypeStruct((m_padded, chunk), jnp.float32),) * 3
 
-    out = pl.pallas_call(
+    out = pallas_call(
         functools.partial(_chunk_kernel_body, cfg=cfg, mb=mb, chunk=chunk,
-                          scan=scan, agent_chunk=agent_chunk,
-                          stats_only=stats_only),
+                          agent_chunk=agent_chunk, stats_only=stats_only),
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shapes,
         interpret=interpret,
-        **kwargs,
     )(*operands)
 
     out = tuple(x[:M] for x in out)
@@ -417,13 +441,10 @@ def kinetic_clearing_chunk(
     return out
 
 
-@functools.partial(
-    jax.jit, static_argnames=("cfg", "mb", "scan", "interpret")
-)
+@functools.partial(jax.jit, static_argnames=("cfg", "mb", "interpret"))
 def kinetic_clearing(
     bid: jax.Array, ask: jax.Array, last: jax.Array, pmid: jax.Array,
-    *, cfg: MarketConfig, mb: int = 8, scan: str = "cumsum",
-    interpret: bool = False,
+    *, cfg: MarketConfig, mb: int = 8, interpret: bool = False,
 ) -> Tuple[jax.Array, ...]:
     """Run the full S-step ensemble simulation in one persistent kernel.
 
@@ -442,12 +463,6 @@ def kinetic_clearing(
     scalar_spec = pl.BlockSpec((mb, 1), lambda i: (i, 0))
     path_spec = pl.BlockSpec((mb, S), lambda i: (i, 0))
 
-    kwargs = {}
-    if pltpu is not None and not interpret:
-        kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel",),
-        )
-
     out_shapes = (
         jax.ShapeDtypeStruct((M, L), jnp.float32),
         jax.ShapeDtypeStruct((M, L), jnp.float32),
@@ -456,13 +471,12 @@ def kinetic_clearing(
         jax.ShapeDtypeStruct((M, S), jnp.float32),
         jax.ShapeDtypeStruct((M, S), jnp.float32),
     )
-    return pl.pallas_call(
-        functools.partial(_kernel_body, cfg=cfg, mb=mb, scan=scan),
+    return pallas_call(
+        functools.partial(_kernel_body, cfg=cfg, mb=mb),
         grid=grid,
         in_specs=[book_spec, book_spec, scalar_spec, scalar_spec],
         out_specs=(book_spec, book_spec, scalar_spec, scalar_spec,
                    path_spec, path_spec),
         out_shape=out_shapes,
         interpret=interpret,
-        **kwargs,
     )(bid, ask, last, pmid)

@@ -10,9 +10,9 @@ Identical device-side semantics (same ``agents.decide``, same
 
 On TPU the GPU notion of a "one-thread serial scan" has no analogue (the VPU
 is always SIMD over lanes), so this ablation isolates the *persistence* axis;
-the scan-depth axis is exercised separately via the ``scan=`` mode flag
-('hillis-steele' log-depth vs 'cumsum'). The performance gap between this and
-:mod:`kinetic_clearing` is a clean attribution to state residency (§IV-I).
+both kernels clear with the same log-depth Hillis-Steele scan. The
+performance gap between this and :mod:`kinetic_clearing` is a clean
+attribution to state residency (§IV-I).
 
 Scenario configs (archetype mixtures, flash-crash shocks, regimes) dispatch
 branch-free inside the shared ``simulate_step``, so this ablation stays
@@ -41,9 +41,10 @@ from repro.core.config import MarketConfig
 from repro.core.params import MarketParams
 from repro.core.step import MarketState, resolve_peer_mids, simulate_step
 from repro.kernels.autotune import pad_to_multiple
-from repro.kernels.kinetic_clearing import (NUM_PARAM_OPERANDS, _pad_rows,
-                                            pad_params, pick_tile,
-                                            resolve_params)
+from repro.kernels.kinetic_clearing import (KERNEL_SCAN, NUM_PARAM_OPERANDS,
+                                            _pad_rows, pad_params,
+                                            pallas_call, resolve_params,
+                                            tile_market_ids)
 
 
 def _step_kernel_body(
@@ -51,16 +52,16 @@ def _step_kernel_body(
     bid_ref, ask_ref, last_ref, pmid_ref,
     out_bid_ref, out_ask_ref, out_last_ref, out_pmid_ref,
     price_ref, volume_ref,
-    *, cfg: MarketConfig, mb: int, scan: str,
+    *, cfg: MarketConfig, mb: int,
 ):
-    i = pl.program_id(0)
     s = step_ref[0, 0]
-    market_ids = (i * mb + jnp.arange(mb, dtype=jnp.int32))[:, None]
+    market_ids = tile_market_ids(mb)
     state = MarketState(
         bid=bid_ref[...], ask=ask_ref[...],
         last_price=last_ref[...], prev_mid=pmid_ref[...],
     )
-    new_state, out = simulate_step(cfg, state, s, market_ids, jnp, scan=scan)
+    new_state, out = simulate_step(cfg, state, s, market_ids, jnp,
+                                   scan=KERNEL_SCAN)
     out_bid_ref[...] = new_state.bid
     out_ask_ref[...] = new_state.ask
     out_last_ref[...] = new_state.last_price
@@ -69,11 +70,10 @@ def _step_kernel_body(
     volume_ref[...] = out.volume
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "mb", "scan", "interpret"))
+@functools.partial(jax.jit, static_argnames=("cfg", "mb", "interpret"))
 def naive_clearing(
     bid: jax.Array, ask: jax.Array, last: jax.Array, pmid: jax.Array,
-    *, cfg: MarketConfig, mb: int = 8, scan: str = "cumsum",
-    interpret: bool = False,
+    *, cfg: MarketConfig, mb: int = 8, interpret: bool = False,
 ) -> Tuple[jax.Array, ...]:
     """S launches of a single-step kernel; state resides in HBM between steps."""
     M, L = bid.shape
@@ -94,8 +94,8 @@ def naive_clearing(
         jax.ShapeDtypeStruct((M, 1), jnp.float32),
         jax.ShapeDtypeStruct((M, 1), jnp.float32),
     )
-    step_call = pl.pallas_call(
-        functools.partial(_step_kernel_body, cfg=cfg, mb=mb, scan=scan),
+    step_call = pallas_call(
+        functools.partial(_step_kernel_body, cfg=cfg, mb=mb),
         grid=grid,
         in_specs=[step_spec, book_spec, book_spec, scalar_spec, scalar_spec],
         out_specs=(book_spec, book_spec, scalar_spec, scalar_spec,
@@ -124,7 +124,7 @@ def _chunk_step_kernel_body(
     bid_ref, ask_ref, last_ref, pmid_ref, ext_buy_ref, ext_ask_ref,
     peer_ref,
     *refs,
-    cfg, mb: int, scan: str, agent_chunk: Optional[int],
+    cfg, mb: int, agent_chunk: Optional[int],
 ):
     """Per-step kernel with external-order inputs (Session API variant).
 
@@ -145,7 +145,7 @@ def _chunk_step_kernel_body(
         last_price=last_ref[...], prev_mid=pmid_ref[...],
     )
     new_state, out = simulate_step(
-        cfg, state, s, market_ids, jnp, scan=scan,
+        cfg, state, s, market_ids, jnp, scan=KERNEL_SCAN,
         ext_buy=ext_buy_ref[...], ext_ask=ext_ask_ref[...],
         agent_chunk=agent_chunk, params=params, peer_mid=peer_ref[...],
     )
@@ -162,7 +162,7 @@ def naive_clearing_chunk(
     bid: jax.Array, ask: jax.Array, last: jax.Array, pmid: jax.Array,
     step0: jax.Array, n_valid: jax.Array,
     ext_buy: jax.Array, ext_ask: jax.Array,
-    *, cfg, chunk: int, mb: int = 8, scan: str = "cumsum",
+    *, cfg, chunk: int, mb: int = 8,
     interpret: bool = False, market_ids: Optional[jax.Array] = None,
     agent_chunk: Optional[int] = None,
     params: Optional[MarketParams] = None,
@@ -214,8 +214,8 @@ def naive_clearing_chunk(
         jax.ShapeDtypeStruct((m_padded, 1), jnp.float32),
         jax.ShapeDtypeStruct((m_padded, 1), jnp.float32),
     )
-    step_call = pl.pallas_call(
-        functools.partial(_chunk_step_kernel_body, cfg=cfg, mb=mb, scan=scan,
+    step_call = pallas_call(
+        functools.partial(_chunk_step_kernel_body, cfg=cfg, mb=mb,
                           agent_chunk=agent_chunk),
         grid=grid,
         in_specs=[step_spec, scalar_spec, book_spec, book_spec, scalar_spec,

@@ -1,8 +1,9 @@
-"""Session runners + public wrappers for the Pallas engines.
+"""Session runners for the Pallas engines.
 
-On this CPU container the kernels execute in ``interpret=True`` mode (the
-kernel body runs as pure JAX ops — bit-exact semantics); on TPU the same
-entry points lower via Mosaic. ``interpret=None`` auto-detects.
+On a TPU backend the kernels lower through Mosaic; on any other backend
+(the CPU test runs) they execute in Pallas interpret mode, where the kernel
+body runs as plain JAX ops with the same bits. The runner decides from
+``jax.default_backend()``; there is no option to force either mode.
 
 Both Pallas families are plumbed through the Session API: the chunk entries
 (:func:`repro.kernels.kinetic_clearing.kinetic_clearing_chunk`,
@@ -12,8 +13,7 @@ Both Pallas families are plumbed through the Session API: the chunk entries
 length, so one trace serves any requested step count *and any scenario
 mixture*; the runner jits them with donated state buffers (params are
 never donated — a session's scenario operands persist device-resident).
-``simulate_kinetic``/``simulate_naive`` remain one-session compatibility
-wrappers registered behind ``engine.simulate``.
+``engine.simulate`` reaches them through the same session factories.
 
 Scaling knobs (Engine backend_opts, all composable):
 
@@ -30,7 +30,7 @@ Scaling knobs (Engine backend_opts, all composable):
   * ``mb=`` / ``agent_chunk=`` / ``autotune=`` — tile selection. By default
     the market axis is padded to sublane-aligned MB=8 tiles
     (:func:`repro.kernels.autotune.auto_tile`); ``autotune=True`` (or
-    ``"auto"``, which sweeps only when lowering via Mosaic on real TPU)
+    ``"auto"``, which sweeps only when lowering via Mosaic on a TPU)
     times (MB, agent-chunk) candidates on first compile and caches the
     winner per ``(device-kind, L, A, chunk)`` for every engine in the
     process.
@@ -42,26 +42,18 @@ from typing import Any, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import PartitionSpec as P
 
 from repro.core import session
 from repro.core import stats as stats_mod
 from repro.core.params import EnsembleSpec, MarketParams
-from repro.core.result import SimResult
 from repro.core.step import MarketState, StepOutput, initial_state
 from repro.kernels import autotune as tune
 from repro.kernels.kinetic_clearing import (_pad_rows, kinetic_clearing_chunk,
-                                            pad_params, pick_tile)
+                                            pad_params)
 from repro.kernels.naive_clearing import naive_clearing_chunk
 from repro.launch.mesh import make_markets_mesh
 from repro.launch.sharding import market_sharding, replicated_sharding
-
-
-def _auto_interpret(interpret: Optional[bool]) -> bool:
-    if interpret is None:
-        return jax.default_backend() != "tpu"
-    return interpret
 
 
 def _resolve_mesh(mesh, devices):
@@ -90,8 +82,7 @@ class PallasChunkRunner(session.ChunkRunner):
     env_runtime_seed = False  # the kernel trace bakes the RNG seed
 
     def __init__(self, kernel_chunk_fn, spec: EnsembleSpec, chunk: int,
-                 mb: Optional[int], scan: str, interpret: Optional[bool],
-                 stats_only: bool = False,
+                 mb: Optional[int] = None, stats_only: bool = False,
                  agent_chunk: Optional[int] = None,
                  devices: Optional[int] = None, mesh=None,
                  autotune="auto"):
@@ -99,9 +90,8 @@ class PallasChunkRunner(session.ChunkRunner):
         self.spec = spec
         self.chunk = int(chunk)
         self.stats_only = bool(stats_only)
-        interpret = _auto_interpret(interpret)
-        self._interpret = interpret
-        self._scan = scan
+        #: Pallas interpret mode: only away from a TPU (see module doc).
+        self.interpret = jax.default_backend() != "tpu"
         self._kernel_chunk_fn = kernel_chunk_fn
         self._mesh = _resolve_mesh(mesh, devices)
         M, L = spec.num_markets, spec.num_levels
@@ -112,7 +102,7 @@ class PallasChunkRunner(session.ChunkRunner):
         self._n_shards = n_shards
         m_local = -(-M // n_shards)
         self.tile = self._resolve_tile(kernel_chunk_fn, spec, m_local, mb,
-                                       agent_chunk, scan, interpret, autotune)
+                                       agent_chunk, autotune)
 
         self._zero_ext = (jnp.zeros((M, L), jnp.float32),
                           jnp.zeros((M, L), jnp.float32))
@@ -157,7 +147,7 @@ class PallasChunkRunner(session.ChunkRunner):
         kernel_chunk_fn = self._kernel_chunk_fn
         M = spec.num_markets
         kernel_kwargs = dict(cfg=spec, chunk=chunk, mb=self.tile.mb,
-                             scan=self._scan, interpret=self._interpret,
+                             interpret=self.interpret,
                              agent_chunk=self.tile.agent_chunk,
                              stats_only=stats_only)
 
@@ -208,14 +198,14 @@ class PallasChunkRunner(session.ChunkRunner):
 
         row_params = MarketParams(*(P("markets", None),)
                                   * len(MarketParams._fields))
-        sharded_call = shard_map(
+        sharded_call = jax.shard_map(
             shard_body, mesh=mesh_,
             in_specs=(P(), P(), P("markets", None), P("markets", None),
                       P("markets", None), P("markets", None),
                       P("markets", None), P("markets", None),
                       P("markets", None), row_params,
                       P("markets", None) if stats_only else None),
-            out_specs=P("markets", None), check_rep=False)
+            out_specs=P("markets", None), check_vma=False)
 
         def pure_chunk(state, stats, params, step0, n_valid,
                        ext_buy, ext_ask):
@@ -260,13 +250,14 @@ class PallasChunkRunner(session.ChunkRunner):
 
     # ---- tile selection ----
     def _resolve_tile(self, kernel_chunk_fn, spec, m_local, mb, agent_chunk,
-                      scan, interpret, autotune) -> tune.TileChoice:
+                      autotune) -> tune.TileChoice:
         if mb is not None:
             return tune.TileChoice(
                 mb=mb, m_padded=tune.pad_to_multiple(m_local, mb),
                 agent_chunk=(agent_chunk if agent_chunk is not None
                              else tune.default_agent_chunk(spec.num_agents)))
-        sweep = autotune is True or (autotune == "auto" and not interpret)
+        sweep = autotune is True or (autotune == "auto"
+                                     and not self.interpret)
         heuristic = tune.auto_tile(m_local, spec.num_agents)
         if agent_chunk is not None:
             heuristic = heuristic._replace(agent_chunk=agent_chunk)
@@ -287,18 +278,18 @@ class PallasChunkRunner(session.ChunkRunner):
             def fn():
                 return kernel_chunk_fn(
                     bid, bid, scalars, scalars, step0, nv, bid, bid,
-                    cfg=spec, chunk=self.chunk, mb=choice.mb, scan=scan,
-                    interpret=interpret, agent_chunk=choice.agent_chunk,
+                    cfg=spec, chunk=self.chunk, mb=choice.mb,
+                    interpret=self.interpret, agent_chunk=choice.agent_chunk,
                     params=zp, stats=st, stats_only=self.stats_only)
 
             return tune.time_call(fn, jax.block_until_ready)
 
         # An explicitly pinned agent_chunk is never swept away, and distinct
-        # kernel configurations (family / scan / stats mode) never share a
-        # measured winner.
+        # kernel configurations (family / stats mode) never share a measured
+        # winner.
         key = tune.tune_key(
             spec.num_levels, spec.num_agents, self.chunk,
-            kernel=kernel_chunk_fn.__name__, scan=scan,
+            kernel=kernel_chunk_fn.__name__,
             stats_only=self.stats_only, agent_chunk=agent_chunk)
         cands = tune.candidate_tiles(
             m_local, spec.num_agents,
@@ -371,44 +362,14 @@ class PallasChunkRunner(session.ChunkRunner):
 
 
 @session.register_backend("pallas-kinetic")
-def open_kinetic_runner(spec, chunk: int, mb=None,
-                        scan: str = "cumsum",
-                        interpret: Optional[bool] = None,
-                        **opts: Any) -> PallasChunkRunner:
+def open_kinetic_runner(spec, chunk: int, **opts: Any) -> PallasChunkRunner:
     """The paper's engine: persistent, VMEM-resident, one launch per chunk."""
     return PallasChunkRunner(kinetic_clearing_chunk, EnsembleSpec.coerce(spec),
-                             chunk, mb=mb, scan=scan, interpret=interpret,
-                             **opts)
+                             chunk, **opts)
 
 
 @session.register_backend("pallas-naive")
-def open_naive_runner(spec, chunk: int, mb=None,
-                      scan: str = "cumsum",
-                      interpret: Optional[bool] = None,
-                      **opts: Any) -> PallasChunkRunner:
+def open_naive_runner(spec, chunk: int, **opts: Any) -> PallasChunkRunner:
     """Ablation: per-step kernel launches, HBM-resident book."""
     return PallasChunkRunner(naive_clearing_chunk, EnsembleSpec.coerce(spec),
-                             chunk, mb=mb, scan=scan, interpret=interpret,
-                             **opts)
-
-
-def _simulate_with(factory, cfg, **opts: Any) -> SimResult:
-    spec = EnsembleSpec.coerce(cfg)
-    runner = factory(spec, min(session.DEFAULT_CHUNK, spec.num_steps), **opts)
-    return session.run_runner_to_result(runner, spec)
-
-
-def simulate_kinetic(cfg, mb=None, scan: str = "cumsum",
-                     interpret: Optional[bool] = None,
-                     **opts: Any) -> SimResult:
-    """Compatibility wrapper: one-session run of the persistent engine."""
-    return _simulate_with(open_kinetic_runner, cfg, mb=mb, scan=scan,
-                          interpret=interpret, **opts)
-
-
-def simulate_naive(cfg, mb=None, scan: str = "cumsum",
-                   interpret: Optional[bool] = None,
-                   **opts: Any) -> SimResult:
-    """Compatibility wrapper: one-session run of the per-step ablation."""
-    return _simulate_with(open_naive_runner, cfg, mb=mb, scan=scan,
-                          interpret=interpret, **opts)
+                             chunk, **opts)

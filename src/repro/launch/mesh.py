@@ -8,23 +8,6 @@ from __future__ import annotations
 import jax
 
 
-def _axis_type_kwargs(num_axes: int) -> dict:
-    """``axis_types=`` kwargs for ``jax.make_mesh`` where supported.
-
-    ``jax.sharding.AxisType`` only exists from jax 0.5; on 0.4.x meshes are
-    implicitly Auto, so passing nothing is semantically equivalent.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * num_axes}
-
-
-def make_mesh_compat(shape, axes, **kwargs):
-    """``jax.make_mesh`` with explicit-Auto axis types on jax >= 0.5."""
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)), **kwargs)
-
-
 def make_markets_mesh(devices=None, skip=()):
     """1-D mesh over the market (ensemble) axis for sharded simulation runs.
 
@@ -51,7 +34,8 @@ def make_markets_mesh(devices=None, skip=()):
             f"requested {n} devices; have {len(avail)} "
             "(hint: XLA_FLAGS=--xla_force_host_platform_device_count=N "
             "forces N host devices on CPU)")
-    return make_mesh_compat((n,), ("markets",), devices=avail[:n])
+    return jax.make_mesh((n,), ("markets",), devices=avail[:n],
+                         axis_types=(jax.sharding.AxisType.Auto,))
 
 
 # TPU v5e hardware model used by the roofline analysis (EXPERIMENTS.md §Roofline)

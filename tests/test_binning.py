@@ -165,7 +165,7 @@ class TestAutoTile:
         tune.clear_tune_cache()
         try:
             def boom(choice):
-                raise RuntimeError("tile rejected")
+                raise RuntimeError("RESOURCE_EXHAUSTED: tile exceeds VMEM")
 
             fb = auto_tile(63, 256)  # keeps the A-derived agent_chunk
             got = tune.autotune_tile(tune.tune_key(32, 256, 4, kernel="k"),
@@ -174,6 +174,37 @@ class TestAutoTile:
             assert got == fb
         finally:
             tune.clear_tune_cache()
+
+    def test_sweep_reraises_non_oom_failure(self):
+        """A compile rejection is a bug to surface, not a tile to skip."""
+        from repro.kernels import autotune as tune
+
+        tune.clear_tune_cache()
+        try:
+            def rejected(choice):
+                raise RuntimeError("Mosaic failed to compile TPU kernel: "
+                                   "unsupported op")
+
+            with pytest.raises(RuntimeError, match="Mosaic"):
+                tune.autotune_tile(tune.tune_key(32, 256, 4, kernel="k"),
+                                   rejected, candidate_tiles(63, 256),
+                                   fallback=auto_tile(63, 256),
+                                   num_markets=63)
+            assert tune.last_sweep_report() is None
+        finally:
+            tune.clear_tune_cache()
+
+    @pytest.mark.parametrize("text,oom", [
+        ("RESOURCE_EXHAUSTED: Ran out of memory in memory space vmem", True),
+        ("XlaRuntimeError: out of memory", True),
+        ("device OOM while allocating", True),
+        ("Mosaic failed to compile TPU kernel: unsupported cast", False),
+        ("not enough room in the grid", False),
+    ])
+    def test_is_oom_error(self, text, oom):
+        from repro.kernels import autotune as tune
+
+        assert tune.is_oom_error(RuntimeError(text)) is oom
 
 
 @pytest.mark.parametrize("agent_chunk", [1, 3, 16, 200])

@@ -174,6 +174,7 @@ def test_plan_validates_chunk_alignment():
 def _run_probe(code: str, devices: int = 2) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
+    env["JAX_PLATFORMS"] = "cpu"  # host devices only
     env["PYTHONPATH"] = SRC
     out = subprocess.run([sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, timeout=560)
@@ -437,6 +438,7 @@ def test_serve_crash_restart_resumes_bitwise(tmp_path):
                                        np.asarray(fr.price).tolist())
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC
+    env["JAX_PLATFORMS"] = "cpu"  # host devices only
     d = str(tmp_path / "crash")
     out1, out2 = str(tmp_path / "phase1.jsonl"), str(tmp_path / "p2.jsonl")
     p1 = subprocess.run([sys.executable, "-c", _CRASH_PHASE1, d, out1],

@@ -30,6 +30,7 @@ def _device_count() -> int:
 def _run_probe(code: str, devices: int = 8) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
+    env["JAX_PLATFORMS"] = "cpu"  # host devices only
     env["PYTHONPATH"] = SRC
     out = subprocess.run([sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, timeout=560)
@@ -206,8 +207,8 @@ def test_sharded_matmul_collectives_detected():
         import jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.launch.hlo_analysis import summarize
-        from repro.launch.mesh import make_mesh_compat
-        mesh = make_mesh_compat((8,), ("model",))
+        mesh = jax.make_mesh((8,), ("model",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         n = 128
         def g(x, w1, w2):
             return ((x @ w1) @ w2).sum()
@@ -226,14 +227,14 @@ def test_sharded_matmul_collectives_detected():
 
 
 def test_market_sharding_requires_markets_axis():
-    from jax.sharding import PartitionSpec as P
+    import jax
+    from jax.sharding import AxisType, PartitionSpec as P
 
-    from repro.launch.mesh import make_mesh_compat
     from repro.launch.sharding import market_sharding, replicated_sharding
 
-    mesh = make_mesh_compat((1,), ("markets",))
+    mesh = jax.make_mesh((1,), ("markets",), axis_types=(AxisType.Auto,))
     assert market_sharding(mesh).spec == P("markets")
     assert replicated_sharding(mesh).spec == P()
-    other = make_mesh_compat((1,), ("data",))
+    other = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     with pytest.raises(ValueError, match="markets"):
         market_sharding(other)

@@ -321,6 +321,7 @@ def test_sharded_mixed_ensemble_parity_subprocess():
     """)
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+    env["JAX_PLATFORMS"] = "cpu"  # host devices only
     env["PYTHONPATH"] = SRC
     out = subprocess.run([sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, timeout=560)

@@ -577,6 +577,7 @@ def test_sharded_env_rollout_parity_subprocess():
     devices in a child process, runnable anywhere)."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+    env["JAX_PLATFORMS"] = "cpu"  # host devices only
     env["PYTHONPATH"] = SRC
     out = subprocess.run([sys.executable, "-c", _SHARDED_ENV_CODE], env=env,
                          capture_output=True, text=True, timeout=560)
@@ -654,6 +655,7 @@ def test_vmap_seeds_mixture_sharded_composition_subprocess():
     mixture (PR-3 sharding × PR-4 ensembles × PR-5 env, finally composed)."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+    env["JAX_PLATFORMS"] = "cpu"  # host devices only
     env["PYTHONPATH"] = SRC
     out = subprocess.run([sys.executable, "-c", _VMAP_MIX_SHARDED_CODE],
                          env=env, capture_output=True, text=True,

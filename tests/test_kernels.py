@@ -13,12 +13,12 @@ from repro.kernels.naive_clearing import naive_clearing
 FIELDS = ("bid", "ask", "last_price", "prev_mid", "price_path", "volume_path")
 
 
-def _run_kernel(kernel_fn, cfg, mb, scan="cumsum"):
+def _run_kernel(kernel_fn, cfg, mb):
     import jax.numpy as jnp
 
     state = initial_state(cfg, jnp)
     out = kernel_fn(state.bid, state.ask, state.last_price, state.prev_mid,
-                    cfg=cfg, mb=mb, scan=scan, interpret=True)
+                    cfg=cfg, mb=mb, interpret=True)
     return tuple(np.asarray(o) for o in out)
 
 
@@ -55,10 +55,11 @@ def test_kinetic_tile_sweep(mb):
 
 @pytest.mark.parametrize("scan", ["cumsum", "hillis-steele"])
 def test_kinetic_scan_modes(scan):
+    """The kernel's Hillis-Steele scan against an oracle on either scan."""
     cfg = MarketConfig(num_markets=8, num_agents=64, num_levels=128,
                        num_steps=8, seed=9)
-    oracle = ref.simulate_reference(cfg).to_numpy()
-    out = _run_kernel(kinetic_clearing, cfg, mb=4, scan=scan)
+    oracle = ref.simulate_reference(cfg, scan=scan).to_numpy()
+    out = _run_kernel(kinetic_clearing, cfg, mb=4)
     for f, got in zip(FIELDS, out):
         assert (got == np.asarray(getattr(oracle, f))).all()
 
@@ -89,6 +90,26 @@ def test_pick_tile():
     assert pick_tile(6) == 6
     assert pick_tile(7) == 7
     assert pick_tile(12, target=8) == 6
+
+
+@pytest.mark.parametrize("kernel", ["kinetic", "naive"])
+def test_interpret_mode_refused_on_tpu(kernel, monkeypatch):
+    """On a TPU backend the runner picks Mosaic and the kernels refuse the
+    interpreter, so nothing can time or ship it there by mistake."""
+    import jax
+
+    from repro.kernels import ops
+
+    cfg = MarketConfig(num_markets=8, num_agents=8, num_levels=16,
+                       num_steps=2, seed=1)
+    open_runner = {"kinetic": ops.open_kinetic_runner,
+                   "naive": ops.open_naive_runner}[kernel]
+    assert open_runner(cfg, 2, mb=8).interpret  # CPU: interpret mode
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert not open_runner(cfg, 2, mb=8).interpret
+    fn = kinetic_clearing if kernel == "kinetic" else naive_clearing
+    with pytest.raises(ValueError, match="interpret=True on a TPU"):
+        _run_kernel(fn, cfg, mb=8)
 
 
 @pytest.mark.parametrize("kernel", ["pallas-kinetic", "pallas-naive"])

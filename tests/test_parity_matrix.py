@@ -12,8 +12,8 @@ statistics match the CPU reference to within 0.1%. Here the matrix spans
 
 Tier-1 runs a fast 11-case subset spanning all scenarios and mixtures
 (including the whale / hft / informed archetype presets); the full >= 53-
-configuration matrix is ``slow``-marked (nightly CI). A ``tpu``-marked
-case re-runs one configuration with real Mosaic lowering.
+configuration matrix is ``slow``-marked (nightly CI). The same bitwise
+checks through the real Mosaic lowering run on a TPU in ``chip_smoke.py``.
 """
 import numpy as np
 import pytest
@@ -84,12 +84,10 @@ def _config(case):
         seed=FULL_MATRIX.index(case), **MIXTURES[mix])
 
 
-def _assert_parity(case, interpret=True):
+def _assert_parity(case):
     cfg = _config(case)
-    naive = engine.simulate(cfg, backend="pallas-naive",
-                            interpret=interpret).to_numpy()
-    kinetic = engine.simulate(cfg, backend="pallas-kinetic",
-                              interpret=interpret).to_numpy()
+    naive = engine.simulate(cfg, backend="pallas-naive").to_numpy()
+    kinetic = engine.simulate(cfg, backend="pallas-kinetic").to_numpy()
 
     # Claim 1: the two custom engines are bitwise identical, field by field.
     for f in BOOK_FIELDS:
@@ -128,16 +126,6 @@ def test_parity_tier1(case):
 @pytest.mark.parametrize("case", FULL_MATRIX, ids=_case_id)
 def test_parity_full_matrix(case):
     _assert_parity(case)
-
-
-@pytest.mark.tpu
-def test_parity_mosaic_lowering():
-    """One configuration through the real TPU lowering (not interpret)."""
-    import jax
-
-    if jax.default_backend() != "tpu":
-        pytest.skip("requires a TPU backend")
-    _assert_parity(("flash-crash", "paper", SHAPES[1]), interpret=False)
 
 
 # ---- scenario-engine unit checks (fast; ride along with the matrix) ----

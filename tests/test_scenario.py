@@ -38,6 +38,7 @@ FIELDS = ("bid", "ask", "last_price", "prev_mid", "price_path", "volume_path")
 def _run_probe(code: str, devices: int = 2) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
+    env["JAX_PLATFORMS"] = "cpu"  # host devices only
     env["PYTHONPATH"] = SRC
     out = subprocess.run([sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, timeout=560)
