@@ -1,0 +1,8 @@
+"""Share of the traced window in which no operation ran on the device
+(averaged over the chips used), in percent."""
+
+
+def read(ctx):
+    if ctx.trace is None or not ctx.trace.devices:
+        return None
+    return 100.0 * ctx.trace.idle_share
