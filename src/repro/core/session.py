@@ -66,9 +66,9 @@ from __future__ import annotations
 
 import dataclasses
 import itertools
-import time
 from typing import Any, Callable, Dict, Iterator, NamedTuple, Optional, Tuple, Union
 
+import jax
 import numpy as np
 
 from repro.core import params as params_mod
@@ -77,6 +77,7 @@ from repro.core.params import EnsembleSpec, MarketParams
 from repro.core.result import SimResult
 from repro.core.stats import MarketStats, init_stats
 from repro.core.step import MarketState, initial_state
+from repro.ops.metrics import MetricsRegistry, span
 
 #: Default compiled chunk length (steps per device call) for streaming runs.
 DEFAULT_CHUNK = 64
@@ -85,6 +86,12 @@ DEFAULT_CHUNK = 64
 _FACTORIES: Dict[str, Callable[..., "ChunkRunner"]] = {}
 # backend name -> reason string for backends whose registration failed
 _FAILED: Dict[str, str] = {}
+
+
+def _nbytes(tree: Any) -> int:
+    """Bytes held by the arrays of a pytree (other leaves count 0)."""
+    return sum(int(getattr(x, "nbytes", 0))
+               for x in jax.tree_util.tree_leaves(tree))
 
 
 class StepBatch(NamedTuple):
@@ -99,7 +106,18 @@ class StepBatch(NamedTuple):
         return int(self.price.shape[-1])
 
     def to_numpy(self) -> "StepBatch":
-        return StepBatch(*(np.asarray(x) for x in self))
+        """The batch on the host: waits for the device to finish it, then
+        copies each path (``kinetic.to_host`` and its two children). The
+        copies are enqueued first, so the runtime starts all three as soon
+        as it sees the device done, not one after another."""
+        with span("kinetic.to_host", bytes=_nbytes(self)):
+            for x in self:
+                if hasattr(x, "copy_to_host_async"):
+                    x.copy_to_host_async()
+            with span("kinetic.to_host.wait"):
+                jax.block_until_ready(tuple(self))  # no-op for numpy
+            with span("kinetic.to_host.copy"):
+                return StepBatch(*(np.asarray(x) for x in self))
 
     @staticmethod
     def concatenate(batches: "list[StepBatch]", xp=np) -> "StepBatch":
@@ -363,6 +381,7 @@ class Engine:
         self.metrics = bool(metrics)
         self.backend_opts = dict(backend_opts)
         self._runners: Dict[Tuple[Any, ...], ChunkRunner] = {}
+        self._opened = itertools.count()   # the spans' ``session`` argument
         # RL env executables (repro.env), cached under the same
         # shape-semantic keys as the chunk runners: any scenario mixture of
         # one shape trains against one compile.
@@ -407,11 +426,12 @@ class Engine:
             or min(DEFAULT_CHUNK, spec.num_steps)
         registry = None
         if self.metrics if metrics is None else metrics:
-            from repro.ops.metrics import MetricsRegistry
-
             registry = MetricsRegistry()
-        return Session(self, spec, self._runner(spec, max(1, chunk)),
-                       metrics=registry)
+        sid = next(self._opened)
+        with span("kinetic.open", session=sid, markets=spec.num_markets):
+            with span("kinetic.open.runner"):
+                runner = self._runner(spec, max(1, chunk))
+            return Session(self, spec, runner, metrics=registry, sid=sid)
 
     def warm(self, specs, *, chunk_sizes=None, include_step: bool = True):
         """Precompile every executable ``specs`` will need (see
@@ -470,15 +490,19 @@ class Session:
     """
 
     def __init__(self, engine: Engine, spec: EnsembleSpec,
-                 runner: ChunkRunner, metrics=None):
+                 runner: ChunkRunner, metrics=None, sid: int = 0):
         self._engine = engine
         self.spec = spec
         self._runner = runner
+        self._sid = sid
         self._step_runner: Optional[ChunkRunner] = None
-        self._state = runner.init_state(spec)
-        self._params = runner.params_to_device(spec.params)
-        self._aux = runner.init_aux(spec)
-        self._stats = runner.init_stats(spec)
+        with span("kinetic.open.place") as sp:
+            self._state = runner.init_state(spec)
+            self._params = runner.params_to_device(spec.params)
+            self._aux = runner.init_aux(spec)
+            self._stats = runner.init_stats(spec)
+            sp.annotate(bytes=_nbytes((self._state, self._params,
+                                       self._aux, self._stats)))
         self._t = 0
         self._closed = False
         self._active_streams = 0
@@ -602,22 +626,22 @@ class Session:
 
     def _dispatch(self, runner: ChunkRunner, n: int, ext,
                   kind: str) -> StepBatch:
-        """One runner dispatch with host-side metrics sampling around it.
+        """One runner dispatch inside its ``kinetic.dispatch`` span.
 
-        All sampling is strictly outside the jitted call: wall-clock reads
-        and two integer trace-counter reads. Nothing here becomes an
+        All sampling is strictly outside the jitted call: the span's clock
+        reads and two integer trace-counter reads. Nothing here becomes an
         operand of (or inserts a sync into) the compiled executable, so a
-        metrics-on session is bitwise-identical to a metrics-off one.
+        metrics-on session is bitwise-identical to a metrics-off one. The
+        span ends when the call is enqueued, not when the device is done.
         """
         m = self.metrics
+        traces0 = runner.trace_count
+        with span("kinetic.dispatch", m, series=f"{kind}_dispatch_seconds",
+                  session=self._sid, step0=self._t, n=n, kind=kind):
+            self._state, self._aux, batch, self._stats = runner.run(
+                self._state, self._params, self._aux, self._t, n, ext,
+                self._stats)
         if m is not None:
-            traces0 = runner.trace_count
-            t0 = time.perf_counter()
-        self._state, self._aux, batch, self._stats = runner.run(
-            self._state, self._params, self._aux, self._t, n, ext,
-            self._stats)
-        if m is not None:
-            m.observe(f"{kind}_seconds", time.perf_counter() - t0)
             m.inc("steps_total", n)
             if kind == "chunk":
                 m.inc("chunks_total")
@@ -661,20 +685,21 @@ class Session:
         overhead. Returns the one-column :class:`StepBatch` observation.
         """
         self._check_open()
-        if self._step_runner is None:
-            self._step_runner = self._engine._runner(self.spec, 1)
-        return self._dispatch(self._step_runner, 1, self._build_ext(actions),
-                              "step")
+        with span("kinetic.step"):
+            if self._step_runner is None:
+                self._step_runner = self._engine._runner(self.spec, 1)
+            return self._dispatch(self._step_runner, 1,
+                                  self._build_ext(actions), "step")
 
     def _build_ext(self, actions: Any) -> Optional[Tuple[np.ndarray, np.ndarray]]:
         if actions is None:
             return None
         from repro.env import actions as actions_mod
 
-        orders = actions_mod.validate_actions(
-            actions, self.spec.num_markets, self.spec.num_levels)
-        return actions_mod.lower_actions(
-            orders, self.spec.num_markets, self.spec.num_levels, np)
+        M, L = self.spec.num_markets, self.spec.num_levels
+        with span("kinetic.step.orders", bytes=2 * M * L * 4):
+            orders = actions_mod.validate_actions(actions, M, L)
+            return actions_mod.lower_actions(orders, M, L, np)
 
     # ---- slot mutation (the serving gateway's attach/detach hook) ----
     def swap_markets(self, slots, sub: Union[EnsembleSpec, MarketConfig],
@@ -707,31 +732,32 @@ class Session:
                 "apply at chunk boundaries — exhaust or close() the "
                 "iterator first")
         sub = EnsembleSpec.coerce(sub)
-        t0 = time.perf_counter()
-        new_spec = self.spec.replace_markets(slots, sub)  # validates slots
-        idx = np.asarray(slots, dtype=np.int64).reshape(-1)
-        new_state = self._state
-        if reset_books:
-            host = [np.array(np.asarray(x), np.float32) for x in self._state]
-            fresh = initial_state(sub, np)
-            for leaf, src in zip(host, fresh):
-                leaf[idx] = np.asarray(src, np.float32)
-            new_state = self._runner.to_device(MarketState(*host))
-        new_stats = self._stats
-        if self._stats is not None:
-            shost = [np.array(np.asarray(x), np.float32)
-                     for x in self._stats]
-            zero = init_stats(idx.size, np)
-            for leaf, src in zip(shost, zero):
-                leaf[idx] = np.asarray(src, np.float32)
-            new_stats = self._runner.stats_to_device(MarketStats(*shost))
-        # Commit only after every placement succeeded (restore()-style
-        # all-or-nothing: a failed splice leaves the session untouched).
-        self._params = self._runner.params_to_device(new_spec.params)
-        self._state, self._stats = new_state, new_stats
-        self.spec = new_spec
+        with span("kinetic.swap", self.metrics, series="swap_seconds"):
+            # replace_markets validates the slots.
+            new_spec = self.spec.replace_markets(slots, sub)
+            idx = np.asarray(slots, dtype=np.int64).reshape(-1)
+            new_state = self._state
+            if reset_books:
+                host = [np.array(np.asarray(x), np.float32)
+                        for x in self._state]
+                fresh = initial_state(sub, np)
+                for leaf, src in zip(host, fresh):
+                    leaf[idx] = np.asarray(src, np.float32)
+                new_state = self._runner.to_device(MarketState(*host))
+            new_stats = self._stats
+            if self._stats is not None:
+                shost = [np.array(np.asarray(x), np.float32)
+                         for x in self._stats]
+                zero = init_stats(idx.size, np)
+                for leaf, src in zip(shost, zero):
+                    leaf[idx] = np.asarray(src, np.float32)
+                new_stats = self._runner.stats_to_device(MarketStats(*shost))
+            # Commit only after every placement succeeded (restore()-style
+            # all-or-nothing: a failed splice leaves the session untouched).
+            self._params = self._runner.params_to_device(new_spec.params)
+            self._state, self._stats = new_state, new_stats
+            self.spec = new_spec
         if self.metrics is not None:
-            self.metrics.observe("swap_seconds", time.perf_counter() - t0)
             self.metrics.inc("swaps_total", int(idx.size))
 
     # ---- results ----
@@ -770,34 +796,34 @@ class Session:
         cursor).
         """
         self._check_open()
-        t0 = time.perf_counter()
-        snap: Dict[str, Any] = {
-            field: np.asarray(value)
-            for field, value in zip(MarketState._fields, self._state)
-        }
-        snap["t"] = self._t
-        snap["rng"] = self._runner.aux_state(self._aux)
-        snap["seed"] = self.spec.seed
-        snap["num_agents"] = self.spec.num_agents
-        snap["num_steps"] = self.spec.num_steps
-        # Run-length encoded labels: O(blocks), not O(M), in the JSON meta.
-        snap["scenarios"] = [[name, len(list(group))] for name, group
-                             in itertools.groupby(self.spec.scenarios)]
-        snap["params"] = {
-            field: np.asarray(value)
-            for field, value in zip(MarketParams._fields, self._params)
-        }
-        snap["init"] = {
-            "quote_qty": np.asarray(self.spec.initial_quote_qty),
-            "spread": np.asarray(self.spec.initial_spread),
-        }
-        if self._stats is not None:
-            snap["stats"] = {
+        with span("kinetic.snapshot", self.metrics,
+                  series="snapshot_seconds"):
+            snap: Dict[str, Any] = {
                 field: np.asarray(value)
-                for field, value in zip(MarketStats._fields, self._stats)
+                for field, value in zip(MarketState._fields, self._state)
             }
+            snap["t"] = self._t
+            snap["rng"] = self._runner.aux_state(self._aux)
+            snap["seed"] = self.spec.seed
+            snap["num_agents"] = self.spec.num_agents
+            snap["num_steps"] = self.spec.num_steps
+            # Run-length encoded labels: O(blocks), not O(M), in the JSON meta.
+            snap["scenarios"] = [[name, len(list(group))] for name, group
+                                 in itertools.groupby(self.spec.scenarios)]
+            snap["params"] = {
+                field: np.asarray(value)
+                for field, value in zip(MarketParams._fields, self._params)
+            }
+            snap["init"] = {
+                "quote_qty": np.asarray(self.spec.initial_quote_qty),
+                "spread": np.asarray(self.spec.initial_spread),
+            }
+            if self._stats is not None:
+                snap["stats"] = {
+                    field: np.asarray(value)
+                    for field, value in zip(MarketStats._fields, self._stats)
+                }
         if self.metrics is not None:
-            self.metrics.observe("snapshot_seconds", time.perf_counter() - t0)
             self.metrics.inc("snapshots_total")
         return snap
 
@@ -815,6 +841,12 @@ class Session:
         bitwise, because the runner re-places state/params/stats on restore.
         """
         self._check_open()
+        with span("kinetic.restore", self.metrics, series="restore_seconds"):
+            self._restore(snap)
+        if self.metrics is not None:
+            self.metrics.inc("restores_total")
+
+    def _restore(self, snap: Dict[str, Any]) -> None:
         if self._active_streams:
             raise RuntimeError(
                 "restore() during an active stream(): the in-flight "
@@ -823,7 +855,6 @@ class Session:
                 "safe mid-stream — it is chunk-boundary-aligned).")
         from repro.checkpoint.manager import CheckpointShapeError
 
-        t_start = time.perf_counter()
         # seed and num_agents are baked into the compiled trace (they are
         # in the static cache key) yet appear in no restored array's shape
         # (params are [M, 1]; books are [M, L]), so a mismatch would
@@ -906,10 +937,6 @@ class Session:
         self._state, self._t = new_state, new_t
         self.spec, self._params = new_spec, new_params
         self._aux, self._stats = new_aux, new_stats
-        if self.metrics is not None:
-            self.metrics.observe("restore_seconds",
-                                 time.perf_counter() - t_start)
-            self.metrics.inc("restores_total")
 
     def save_checkpoint(self, manager, step: Optional[int] = None,
                         *, wait: bool = True) -> int:

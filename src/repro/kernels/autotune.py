@@ -24,6 +24,8 @@ import re
 import time
 from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 
+from repro.ops.metrics import span
+
 SUBLANES = 8  # TPU f32 sublane count — tiles want MB ≡ 0 (mod 8)
 
 #: Winner cache for the timed sweep: (device_kind, L, A, chunk) -> TileChoice.
@@ -172,16 +174,17 @@ def autotune_tile(key: Tuple,
     if cached is None:
         best, best_t = None, float("inf")
         failures = []
-        for cand in cands:
-            try:
-                t = time_candidate(cand)
-            except Exception as exc:
-                if not is_oom_error(exc):
-                    raise
-                failures.append(f"{cand!r}: {type(exc).__name__}: {exc}")
-                continue
-            if t < best_t:
-                best, best_t = cand, t
+        with span("kinetic.open.autotune", candidates=len(cands)):
+            for cand in cands:
+                try:
+                    t = time_candidate(cand)
+                except Exception as exc:
+                    if not is_oom_error(exc):
+                        raise
+                    failures.append(f"{cand!r}: {type(exc).__name__}: {exc}")
+                    continue
+                if t < best_t:
+                    best, best_t = cand, t
         fell_back = best is None
         if fell_back:  # every candidate failed: the heuristic choice
             best = fallback if fallback is not None else auto_tile(

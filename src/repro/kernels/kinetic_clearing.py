@@ -80,9 +80,13 @@ NUM_PARAM_OPERANDS = len(MarketParams._fields)
 KERNEL_SCAN = "hillis-steele"
 
 
-def pallas_call(body, *, interpret: bool, **kwargs):
+def pallas_call(body, *, name: str, interpret: bool, **kwargs):
     """``pl.pallas_call`` for the clearing kernels: Mosaic with a parallel
     market grid, or the interpreter for tests on a host without a TPU.
+
+    ``name`` is the kernel's name in the compiled program, so a profiler
+    trace shows it by name (``kinetic_clearing_chunk``,
+    ``kinetic_clearing_step``, ``naive_clearing_step``).
 
     Interpret mode is refused on a TPU backend, so no caller can time or
     ship the interpreter by mistake.
@@ -94,7 +98,7 @@ def pallas_call(body, *, interpret: bool, **kwargs):
     else:
         kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel",))
-    return pl.pallas_call(body, interpret=interpret, **kwargs)
+    return pl.pallas_call(body, name=name, interpret=interpret, **kwargs)
 
 
 def write_column(path, s, col):
@@ -428,6 +432,8 @@ def kinetic_clearing_chunk(
     out = pallas_call(
         functools.partial(_chunk_kernel_body, cfg=cfg, mb=mb, chunk=chunk,
                           agent_chunk=agent_chunk, stats_only=stats_only),
+        name="kinetic_clearing_step" if chunk == 1
+        else "kinetic_clearing_chunk",
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
@@ -473,6 +479,7 @@ def kinetic_clearing(
     )
     return pallas_call(
         functools.partial(_kernel_body, cfg=cfg, mb=mb),
+        name="kinetic_clearing_chunk",
         grid=grid,
         in_specs=[book_spec, book_spec, scalar_spec, scalar_spec],
         out_specs=(book_spec, book_spec, scalar_spec, scalar_spec,

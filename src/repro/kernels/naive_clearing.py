@@ -96,6 +96,7 @@ def naive_clearing(
     )
     step_call = pallas_call(
         functools.partial(_step_kernel_body, cfg=cfg, mb=mb),
+        name="naive_clearing_step",
         grid=grid,
         in_specs=[step_spec, book_spec, book_spec, scalar_spec, scalar_spec],
         out_specs=(book_spec, book_spec, scalar_spec, scalar_spec,
@@ -217,6 +218,7 @@ def naive_clearing_chunk(
     step_call = pallas_call(
         functools.partial(_chunk_step_kernel_body, cfg=cfg, mb=mb,
                           agent_chunk=agent_chunk),
+        name="naive_clearing_step",
         grid=grid,
         in_specs=[step_spec, scalar_spec, book_spec, book_spec, scalar_spec,
                   scalar_spec, book_spec, book_spec, scalar_spec]

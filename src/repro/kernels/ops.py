@@ -54,6 +54,7 @@ from repro.kernels.kinetic_clearing import (_pad_rows, kinetic_clearing_chunk,
 from repro.kernels.naive_clearing import naive_clearing_chunk
 from repro.launch.mesh import make_markets_mesh
 from repro.launch.sharding import market_sharding, replicated_sharding
+from repro.ops.metrics import span
 
 
 def _resolve_mesh(mesh, devices):
@@ -346,19 +347,25 @@ class PallasChunkRunner(session.ChunkRunner):
             step0: int, n: int, ext,
             stats=None) -> Tuple[MarketState, Any, session.StepBatch, Any]:
         eb, ea = self._zero_ext if ext is None else ext
-        step0_arr = jnp.full((1, 1), step0, dtype=jnp.int32)
-        nvalid_arr = jnp.full((1, 1), n, dtype=jnp.int32)
-        new_state, payload = self._chunk_fn(
-            state, stats if self.stats_only else None, params,
-            step0_arr, nvalid_arr, jnp.asarray(eb), jnp.asarray(ea))
+        host_bytes = 8 + (0 if ext is None else eb.nbytes + ea.nbytes)
+        with span("kinetic.dispatch.operands", bytes=host_bytes):
+            step0_arr = jnp.full((1, 1), step0, dtype=jnp.int32)
+            nvalid_arr = jnp.full((1, 1), n, dtype=jnp.int32)
+            eb, ea = jnp.asarray(eb), jnp.asarray(ea)
+        with span("kinetic.dispatch.launch"):
+            new_state, payload = self._chunk_fn(
+                state, stats if self.stats_only else None, params,
+                step0_arr, nvalid_arr, eb, ea)
         if self.stats_only:
             empty = jnp.zeros((self.spec.num_markets, 0), jnp.float32)
             return (new_state, aux,
                     session.StepBatch(price=empty, volume=empty, mid=empty),
                     payload)
-        pp, vp, mp = payload
-        return new_state, aux, session.StepBatch(
-            price=pp[:, :n], volume=vp[:, :n], mid=mp[:, :n]), None
+        with span("kinetic.dispatch.slice"):
+            pp, vp, mp = payload
+            batch = session.StepBatch(
+                price=pp[:, :n], volume=vp[:, :n], mid=mp[:, :n])
+        return new_state, aux, batch, None
 
 
 @session.register_backend("pallas-kinetic")

@@ -1,26 +1,62 @@
-"""Zero-hot-path metrics surface: per-session counters, gauges and timings.
+"""Zero-hot-path observability: profiler spans, counters, gauges, timings.
 
-A :class:`MetricsRegistry` is attached to every session ``Engine.open``
-creates (disable with ``Engine(backend, metrics=False)`` or per-session
-``open(spec, metrics=False)``). Everything it records is sampled on the
-*host*, strictly outside the jitted graph:
+Two surfaces, both sampled on the *host* strictly outside every jitted or
+Pallas function, so no value becomes an operand of a compiled executable:
+metrics cause **zero additional traces** and results stay bitwise-identical
+to a metrics-off session, with or without a profiler recording (asserted by
+``tests/test_ops.py::test_metrics_zero_traces_and_bitwise``).
 
-  * no value ever becomes an operand of a compiled executable, so metrics
-    collection causes **zero additional traces** and results stay
-    bitwise-identical to a metrics-off session (asserted by the tier-1
-    test ``tests/test_ops.py::test_metrics_zero_traces_and_bitwise``);
-  * chunk/step timings are dispatch wall-times around the existing host
-    call sites (no ``block_until_ready`` is inserted — blocking would
-    perturb the very latency being observed);
-  * the retrace counter samples the runner's Python-side trace counter
-    before/after each dispatch — two integer reads per chunk.
+**Spans.** :class:`span` opens a ``jax.profiler.TraceAnnotation``: while a
+profiler records, the span lands on its host plane on the same clock as the
+device planes, its keyword arguments as the event's stats; parentage is
+nesting on the host thread. With no profiler recording a span costs about
+a microsecond. Given a :class:`MetricsRegistry` it also ``observe``s its
+duration. The program's spans (``kbench/spans.py`` reduces them):
 
-Recorded by the session wiring (see :class:`repro.core.session.Session`):
+  ``kinetic.open``               ``Engine.open`` until the Session is ready
+                                 (``session``, a per-engine counter;
+                                 ``markets``)
+    ``kinetic.open.runner``      the runner lookup: cache hit or factory
+      ``kinetic.open.autotune``  a Pallas tile sweep (cache misses only;
+                                 ``candidates``); under ``kinetic.step``
+                                 when a first step builds its runner
+    ``kinetic.open.place``       state, params, aux and stats placed on the
+                                 device (``bytes``)
+  ``kinetic.step``               ``Session.step``
+    ``kinetic.step.orders``      validation and lowering of the orders to
+                                 dense [M, L] (``bytes``)
+  ``kinetic.dispatch``           one runner call (``session``, ``step0``,
+                                 ``n``, ``kind`` = chunk/step); nests in
+                                 ``kinetic.step`` for a step
+    ``kinetic.dispatch.operands``  Pallas runners: the step0/n_valid
+                                 scalars and the external orders (``bytes``)
+    ``kinetic.dispatch.launch``  the jitted chunk call (enqueue)
+    ``kinetic.dispatch.slice``   the ``[:, :n]`` path slices
+  ``kinetic.to_host``            ``StepBatch.to_numpy`` (``bytes``)
+    ``kinetic.to_host.wait``     waiting for the device to finish the batch
+    ``kinetic.to_host.copy``     the device-to-host copies: enqueued before
+                                 the wait, started by the runtime when it
+                                 sees the device done, as the wait returns
+  ``kinetic.swap``, ``kinetic.snapshot``, ``kinetic.restore``
+                                 the Session's slot splice, snapshot and
+                                 restore
+  ``kinetic.gateway.checkpoint_snapshot``  the gateway checkpoint's
+                                 device-to-host mirror (``seq``); it times
+                                 ``checkpoint_snapshot_seconds``
+
+**Registry.** A :class:`MetricsRegistry` is attached to every session
+``Engine.open`` creates (disable with ``Engine(backend, metrics=False)`` or
+per-session ``open(spec, metrics=False)``). The session records:
 
   counters  ``steps_total``, ``chunks_total``, ``traces`` (retrace counter:
-            0 on a warm engine), ``snapshots_total``, ``restores_total``
-  timings   ``chunk_seconds``, ``step_seconds``, ``snapshot_seconds``,
-            ``restore_seconds``  (count/total/min/max aggregates)
+            0 on a warm engine; two integer reads per dispatch),
+            ``snapshots_total``, ``restores_total``, ``swaps_total``
+  timings   (count/total/min/max aggregates, timed by their spans)
+            ``chunk_dispatch_seconds``, ``step_dispatch_seconds`` — the
+            host time to *enqueue* a chunk or step (``kinetic.dispatch``;
+            the device may still be working when it ends: not a latency
+            and not a throughput), ``swap_seconds``, ``snapshot_seconds``,
+            ``restore_seconds``
   gauges    ``chunk``, ``num_markets``, and on the Pallas engines the
             autotune tile pressure: ``autotune_vmem_bytes``, ``tile_mb``,
             ``tile_agent_chunk``
@@ -30,27 +66,24 @@ additional series. The serving gateway (:mod:`repro.serve`) records:
 
   counters  ``frames_published_total``, ``frames_dropped_total``,
             ``sessions_opened_total``, ``sessions_closed_total``,
-            ``reconnects_total``, ``swaps_total`` (slot attach/detach rows)
-  gauges    ``queue_depth.<client>`` per-client fan-out queue depths,
-            ``clients_connected``, ``slots_attached``
-  windows   ``chunk_latency_seconds`` — a bounded-window
-            :class:`QuantileWindow` whose p50/p99 feed ``BENCH_serve.json``
-
-Durability + fault-storm series (PR 8; all host-side, zero hot-path):
-
-  counters  ``checkpoints_saved_total`` (committed by the async writer),
+            ``reconnects_total``, ``swaps_total`` (slot attach/detach rows),
+            ``checkpoints_saved_total`` (committed by the async writer),
             ``journal_entries_total`` (splices journaled),
             ``journal_compactions_total`` /
             ``journal_entries_compacted_total`` (GC-driven compaction),
             ``recoveries_total`` (successful supervised recovery passes),
             ``recovery_attempts_total`` (including retried failures),
             ``faults_coalesced_total`` (extra faults folded into one pass)
-  gauges    ``checkpoint_writer_pending`` (snapshots not yet committed,
+  gauges    ``queue_depth.<client>`` per-client fan-out queue depths,
+            ``clients_connected``, ``slots_attached``,
+            ``checkpoint_writer_pending`` (snapshots not yet committed,
             0–2 by the lag bound), ``checkpoints_skipped`` (saves dropped
             by the latest-wins mailbox), ``degraded`` (0/1)
-  windows   ``checkpoint_snapshot_seconds`` — the engine-thread cost of a
-            checkpoint (device→host mirror ONLY; `BENCH_serve.json` fails
-            hard when its max stalls past threshold), and
+  windows   (bounded :class:`QuantileWindow` series, p50/p99 read by
+            ``benchmarks/serve_bench.py``)
+            ``chunk_latency_seconds`` — dispatch to materialized frames,
+            ``checkpoint_snapshot_seconds`` — the engine-thread cost of a
+            checkpoint (device→host mirror only; its span's duration),
             ``checkpoint_write_seconds`` — the background writer's
             serialize+fsync+commit latency (never on the engine thread)
 """
@@ -58,7 +91,10 @@ from __future__ import annotations
 
 import bisect
 import threading
+import time
 from typing import Any, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 
 class Aggregate:
@@ -195,24 +231,47 @@ class MetricsRegistry:
         with self._lock:
             return self._windows.get(name)
 
-    def steps_per_s(self) -> float:
-        """Derived throughput: steps dispatched per second of chunk wall
-        time (dispatch-side; see module docstring for the async caveat)."""
-        with self._lock:
-            steps = self._counters.get("steps_total", 0)
-            agg = self._timings.get("chunk_seconds")
-            secs = agg.total if agg is not None else 0.0
-        return steps / secs if secs > 0 else 0.0
-
     def snapshot(self) -> Dict[str, Any]:
-        """Plain-python view: {'counters', 'gauges', 'timings', 'windows',
-        'derived'}."""
+        """Plain-python view: {'counters', 'gauges', 'timings', 'windows'}."""
         with self._lock:
-            out = {
+            return {
                 "counters": dict(self._counters),
                 "gauges": dict(self._gauges),
                 "timings": {k: v.summary() for k, v in self._timings.items()},
                 "windows": {k: v.summary() for k, v in self._windows.items()},
             }
-        out["derived"] = {"steps_per_s": self.steps_per_s()}
-        return out
+
+
+class span:
+    """A host span: ``with span("kinetic.dispatch", registry, n=64): ...``.
+
+    Opens ``jax.profiler.TraceAnnotation(name, **args)``, which records
+    only while a profiler is recording. Given a ``registry``, also
+    ``observe``s the span's duration in seconds under ``series`` (default:
+    ``name``) when the body completes without raising. The duration is
+    ``seconds`` once the span has closed. Host code only: never inside a
+    jitted or Pallas function.
+    """
+
+    __slots__ = ("_ann", "_registry", "_series", "_t0", "seconds")
+
+    def __init__(self, name: str, registry: Optional[MetricsRegistry] = None,
+                 series: Optional[str] = None, **args: Any) -> None:
+        self._ann = TraceAnnotation(name, **args)
+        self._registry = registry
+        self._series = series or name
+
+    def annotate(self, **args: Any) -> None:
+        """Add arguments known only once the span's work is done."""
+        self._ann.set_metadata(**args)
+
+    def __enter__(self) -> "span":
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        self.seconds = time.perf_counter() - self._t0
+        self._ann.__exit__(exc_type, exc, tb)
+        if self._registry is not None and exc_type is None:
+            self._registry.observe(self._series, self.seconds)

@@ -62,6 +62,7 @@ from typing import Any, Dict, List, Optional, Tuple, Union
 from repro.core.config import MarketConfig, scenario_config
 from repro.core.params import EnsembleSpec
 from repro.core.session import Engine, Session, StepBatch
+from repro.ops.metrics import span
 from repro.serve.bus import FrameBus, Subscription
 from repro.serve.frames import Event, Frame, slice_frames
 from repro.serve.journal import SpliceEntry, SpliceJournal
@@ -530,11 +531,11 @@ class Gateway:
         done = self._buffer.push(meta, (batch, stats))
         if (self._ckpt is not None and self.checkpoint_every
                 and (seq + 1) % self.checkpoint_every == 0):
-            t0c = time.perf_counter()
-            sess.save_checkpoint(self._ckpt, wait=False)
+            with span("kinetic.gateway.checkpoint_snapshot", seq=seq) as sp:
+                sess.save_checkpoint(self._ckpt, wait=False)
             if self.metrics is not None:
                 self.metrics.observe_window("checkpoint_snapshot_seconds",
-                                            time.perf_counter() - t0c)
+                                            sp.seconds)
                 self.metrics.gauge("checkpoint_writer_pending",
                                    self._ckpt.pending)
                 self.metrics.gauge("checkpoints_skipped",

@@ -11,6 +11,7 @@ Tier-1 acceptance for the ops hardening:
     the conservative heuristic tile — bitwise-identical results, never a
     crash.
 """
+import contextlib
 import dataclasses
 
 import numpy as np
@@ -20,7 +21,7 @@ from repro.core.config import MarketConfig
 from repro.core.session import DEFAULT_CHUNK, Engine
 from repro.kernels import autotune as tune
 from repro.ops import force_autotune_oom
-from repro.ops.metrics import MetricsRegistry
+from repro.ops.metrics import MetricsRegistry, span
 
 CFG = MarketConfig(num_markets=4, num_agents=16, num_levels=16, num_steps=12,
                    seed=3)
@@ -37,27 +38,44 @@ def _batches_equal(a, b):
 
 # ---- metrics: zero traces, bitwise parity ----
 
-@pytest.mark.parametrize("backend", ["numpy-pcg64", "jax-scan",
-                                     "pallas-kinetic"])
-def test_metrics_zero_traces_and_bitwise(backend):
+@contextlib.contextmanager
+def _profiler(trace_dir):
+    """A ``jax.profiler`` trace recording host spans while entered."""
+    import jax
+
+    jax.profiler.start_trace(str(trace_dir))
+    try:
+        yield
+    finally:
+        jax.profiler.stop_trace()
+
+
+@pytest.mark.parametrize("backend,profiled", [
+    ("numpy-pcg64", False), ("jax-scan", False), ("pallas-kinetic", False),
+    ("jax-scan", True), ("pallas-kinetic", True),
+], ids=["numpy-pcg64", "jax-scan", "pallas-kinetic", "jax-scan-profiled",
+        "pallas-kinetic-profiled"])
+def test_metrics_zero_traces_and_bitwise(backend, profiled, tmp_path):
     """The headline guarantee: a metrics-on session produces bitwise the
-    same stream as a metrics-off session and causes traces_delta == 0."""
+    same stream as a metrics-off session and causes traces_delta == 0 —
+    also while a profiler records every span."""
     eng = Engine(backend)
     off = eng.open(CFG, metrics=False)
     batch_off = off.run(12)
     traces_before = eng.trace_count
 
-    on = eng.open(CFG)  # metrics on by default
-    assert isinstance(on.metrics, MetricsRegistry)
-    batch_on = on.run(12)
+    with (_profiler(tmp_path) if profiled else contextlib.nullcontext()):
+        on = eng.open(CFG)  # metrics on by default
+        assert isinstance(on.metrics, MetricsRegistry)
+        batch_on = on.run(12)
+        assert _batches_equal(batch_off, batch_on)
     assert eng.trace_count - traces_before == 0, "metrics caused a retrace"
-    assert _batches_equal(batch_off, batch_on)
     snap = on.metrics.snapshot()
     assert snap["counters"]["steps_total"] == 12
     assert snap["counters"]["chunks_total"] == 1
     assert snap["counters"].get("traces", 0) == 0  # warm engine
-    assert snap["timings"]["chunk_seconds"]["count"] == 1
-    assert on.metrics.steps_per_s() > 0
+    assert snap["timings"]["chunk_dispatch_seconds"]["count"] == 1
+    assert snap["timings"]["chunk_dispatch_seconds"]["total"] > 0
 
 
 @pytest.mark.parametrize("backend", ALL_BACKENDS)
@@ -74,8 +92,8 @@ def test_metrics_recorded_series(backend):
     assert m["counters"]["snapshots_total"] == 1
     assert m["counters"]["restores_total"] == 1
     assert m["gauges"]["num_markets"] == CFG.num_markets
-    for series in ("chunk_seconds", "step_seconds", "snapshot_seconds",
-                   "restore_seconds"):
+    for series in ("chunk_dispatch_seconds", "step_dispatch_seconds",
+                   "snapshot_seconds", "restore_seconds"):
         assert m["timings"][series]["count"] >= 1, series
     if backend.startswith("pallas"):
         assert m["gauges"]["autotune_vmem_bytes"] > 0
@@ -105,7 +123,50 @@ def test_metrics_registry_aggregates():
     assert agg["total"] == pytest.approx(3.0)
     assert agg["mean"] == pytest.approx(1.0)
     assert snap["gauges"]["g"] == 7
-    assert m.steps_per_s() == 0.0  # no chunk timings recorded
+    # Dispatch time is not a throughput: the snapshot derives none.
+    assert set(snap) == {"counters", "gauges", "timings", "windows"}
+
+
+def test_span_records_into_a_registry_only_when_given_one():
+    m = MetricsRegistry()
+    with span("outer", m, series="outer_seconds"):
+        with span("inner", m, n=3) as inner:
+            pass
+        with span("quiet"):
+            pass
+    with pytest.raises(RuntimeError):
+        with span("failed", m):
+            raise RuntimeError("a failed body is not observed")
+    timings = m.snapshot()["timings"]
+    assert set(timings) == {"outer_seconds", "inner"}
+    assert timings["outer_seconds"]["total"] >= timings["inner"]["total"]
+    assert timings["inner"]["count"] == 1
+    assert timings["inner"]["total"] == inner.seconds    # kept once closed
+
+
+def test_spans_nest_on_the_profiler_host_thread(tmp_path):
+    import glob
+
+    from jax.profiler import ProfileData
+
+    with _profiler(tmp_path):
+        with span("kinetic.outer", session=7, markets=4):
+            with span("kinetic.inner") as inner:
+                inner.annotate(bytes=64)
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    got = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("kinetic."):
+                    got[ev.name] = (plane.name, line.name, ev.start_ns,
+                                    ev.start_ns + ev.duration_ns,
+                                    {k: v for k, v in ev.stats})
+    outer, inner = got["kinetic.outer"], got["kinetic.inner"]
+    assert outer[:2] == inner[:2]                  # one host thread
+    assert outer[2] <= inner[2] and inner[3] <= outer[3]
+    assert outer[4] == {"session": 7, "markets": 4}
+    assert inner[4] == {"bytes": 64}
 
 
 # ---- warm-start controller ----

@@ -513,6 +513,23 @@ def test_stop_flushes_async_checkpoint_writer(tmp_path):
     asyncio.run(main())
 
 
+def test_checkpoint_snapshot_window_is_timed_by_its_span(tmp_path):
+    """Each periodic checkpoint's engine-thread mirror lands in the
+    ``checkpoint_snapshot_seconds`` window, timed by the
+    ``kinetic.gateway.checkpoint_snapshot`` span."""
+    async def main():
+        gw = Gateway(_tpl(2, num_steps=4096), backend="numpy", chunk_size=8,
+                     ckpt_dir=tmp_path, checkpoint_every=1)
+        await gw.start(chunks=3)
+        gw.open_session("baseline", client="c0")
+        assert await gw._sessions["c0"].frames(2)
+        await gw.stop()
+        win = gw.metrics.window("checkpoint_snapshot_seconds").summary()
+        assert win["count"] >= 2
+        assert win["p50"] > 0
+    asyncio.run(main())
+
+
 # ---------------------------------------------------------------------------
 # transports
 # ---------------------------------------------------------------------------

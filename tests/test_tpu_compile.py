@@ -121,3 +121,28 @@ def test_sharded_ring_runner_compiles_for_v5e_2x2(topo, monkeypatch):
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert "collective-permute" in text
+
+
+@pytest.mark.parametrize("kernel,chunk,name", [
+    ("kinetic", CHUNK, "kinetic_clearing_chunk"),
+    ("kinetic", 1, "kinetic_clearing_step"),
+    ("naive", CHUNK, "naive_clearing_step"),
+])
+def test_kernel_carries_its_name_for_v5e(kernel, chunk, name, one_chip):
+    """The Mosaic call is the HLO instruction a device trace shows by name."""
+    import jax
+
+    spec = _spec()
+    args, params, stats = _shapes(spec, one_chip, False)
+
+    def run(*a, params, stats):
+        return KERNELS[kernel](*a, params=params, stats=stats, cfg=spec,
+                               chunk=chunk, mb=8, agent_chunk=128,
+                               interpret=False, stats_only=False)
+
+    text = jax.jit(run).lower(*args, params=params, stats=stats).compile(
+        ).as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert calls
+    assert all(line.lstrip().startswith(f"%{name}") for line in calls)
