@@ -60,30 +60,36 @@ def bin_orders_onehot(side_buy, price, qty, L, xp, agent_chunk=None):
     atomicAdd histogram; exact-integer f32 adds keep it bitwise-identical to
     scatter-based binning.
 
-    ``agent_chunk`` bounds the [M, Ac, L] one-hot intermediate (the dominant
-    VMEM term inside the persistent kernel) by accumulating the contraction
-    over static slices of the agent axis. Because every partial sum is an
-    exact integer in f32, the result is bitwise-identical for any chunking.
+    The one-hot is level-major, ``[M, L, Ac]``: levels on sublanes, agents
+    on lanes, built by comparing a broadcast price row with a level column
+    and selecting 1.0/0.0 in f32. Buy and sell quantities are stacked as
+    the two rows of one ``[M, 2, Ac]`` lhs, so each market and agent chunk
+    takes a single contraction over the agent axis of both operands
+    (``[M, 2, Ac] x [M, L, Ac]``). The two rows also give the lhs the
+    non-contracting dimension Mosaic needs to lower a batched matmul; it
+    latches the level-major one-hot in its transposed mode.
 
-    The quantities carry a unit middle axis (``[M, 1, A] x [M, A, L]``):
-    Mosaic lowers a batched matmul only when the lhs has a non-contracting
-    dimension. Agent slices are cut with plain slices and ``expand_dims``,
-    never mixed indexing, which Mosaic would see as a gather.
+    ``agent_chunk`` bounds the one-hot intermediate (the dominant VMEM term
+    inside the persistent kernel) by accumulating the contraction over
+    static slices of the agent axis. Because every partial sum is an exact
+    integer in f32, the result is bitwise-identical for any chunking. Agent
+    slices are cut with plain slices and ``expand_dims``, never mixed
+    indexing, which Mosaic would see as a gather.
     """
-    levels = xp.arange(L, dtype=xp.int32)
-    qb = xp.expand_dims(qty * side_buy.astype(xp.float32), 1)     # [M, 1, A]
-    qs = xp.expand_dims(qty * (~side_buy).astype(xp.float32), 1)
-    price = xp.expand_dims(price, -1)                              # [M, A, 1]
-    A = price.shape[1]
+    f32 = xp.float32
+    levels = xp.expand_dims(xp.arange(L, dtype=xp.int32), -1)     # [L, 1]
+    q = xp.stack([qty * side_buy.astype(f32),
+                  qty * (~side_buy).astype(f32)], axis=1)         # [M, 2, A]
+    A = price.shape[-1]
     step = A if not agent_chunk else min(agent_chunk, A)
-    buy = sell = None
+    acc = None
     for a0 in range(0, A, step):
         a1 = min(a0 + step, A)
-        onehot = (price[:, a0:a1] == levels).astype(xp.float32)   # [M, Ac, L]
-        b = xp.einsum("mka,mal->mkl", qb[:, :, a0:a1], onehot)[:, 0]
-        s = xp.einsum("mka,mal->mkl", qs[:, :, a0:a1], onehot)[:, 0]
-        buy, sell = (b, s) if buy is None else (buy + b, sell + s)
-    return buy, sell
+        onehot = xp.where(xp.expand_dims(price[:, a0:a1], 1) == levels,
+                          f32(1.0), f32(0.0))                     # [M, L, Ac]
+        part = xp.einsum("mka,mla->mkl", q[:, :, a0:a1], onehot)  # [M, 2, L]
+        acc = part if acc is None else acc + part
+    return acc[:, 0], acc[:, 1]
 
 
 def exact_ratio(num, den, xp):

@@ -14,9 +14,10 @@ under-utilization on TPU. This module replaces that policy:
     ``(device-kind, L, A, chunk)`` so every engine/runner built later in
     the process reuses the measured choice without re-sweeping.
 
-The agent-chunk knob bounds the one-hot binning's [MB, Ac, L] VMEM
-intermediate (see ``bin_orders_onehot``); f32 exact-integer adds make the
-chunked accumulation bitwise-identical for any chunk size.
+The agent-chunk knob bounds the one-hot binning's level-major
+[MB, L, Ac] VMEM intermediate (see ``bin_orders_onehot``); f32
+exact-integer adds make the chunked accumulation bitwise-identical for
+any chunk size.
 """
 from __future__ import annotations
 
@@ -59,9 +60,9 @@ def estimate_vmem_bytes(tile: "TileChoice", num_levels: int,
                         num_agents: int, chunk: int = 1) -> int:
     """Rough per-grid-cell VMEM working set of the clearing kernel, bytes.
 
-    Dominated by the [MB, Ac, L] one-hot binning intermediate, plus the
-    resident books/profiles (6 × [MB, L]) and the per-chunk output paths
-    (3 × [MB, chunk]); all f32. An estimate for dashboards and tile-pressure
+    Dominated by the level-major [MB, L, Ac] one-hot binning intermediate,
+    plus the resident books/profiles (6 × [MB, L]) and the per-chunk output
+    paths (3 × [MB, chunk]); all f32. An estimate for dashboards and tile-pressure
     gauges, not a lowering-accurate allocator model.
     """
     ac = tile.agent_chunk or max(1, num_agents)
@@ -96,7 +97,7 @@ def pad_to_multiple(n: int, multiple: int) -> int:
 
 
 def default_agent_chunk(num_agents: int) -> Optional[int]:
-    """Bound the [MB, Ac, L] one-hot intermediate; small A stays unchunked."""
+    """Bound the [MB, L, Ac] one-hot intermediate; small A stays unchunked."""
     return 128 if num_agents > 128 else None
 
 

@@ -18,10 +18,11 @@ entries *pad* the market axis to a tile multiple instead of shrinking MB, so
 prime/odd M keeps full sublane tiles; see :mod:`repro.kernels.autotune`),
 price ticks on lanes (L multiple of 128 native; smaller L still correct,
 just padded by the compiler). VMEM working set per grid cell ≈
-``7·MB·L + MB·Ac·L (one-hot binning, Ac = agent_chunk ≤ A) + 2·MB·S`` f32
-for path outputs, plus a negligible ``12·MB`` term for the per-market
-parameter columns (the :class:`repro.core.params.MarketParams` operands,
-one ``(MB, 1)`` block each) — padding adds only whole-tile rows, so the
+``7·MB·L + MB·L·Ac (the level-major [MB, L, Ac] one-hot binning, Ac =
+agent_chunk ≤ A) + 2·MB·S`` f32 for path outputs, plus a negligible
+``12·MB`` term for the per-market parameter columns (the
+:class:`repro.core.params.MarketParams` operands, one ``(MB, 1)`` block
+each) — padding adds only whole-tile rows, so the
 padded-tile term is the same ``MB·(...)`` budget with ``grid =
 ceil(M/MB)`` cells. In ``stats_only`` mode the ``2·MB·S`` path term is
 replaced by a constant ``6·MB`` statistics-accumulator term

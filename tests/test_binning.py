@@ -52,6 +52,93 @@ def test_onehot_matches_scatter_exactly(M, A, L):
     assert (got_sell == want_sell).all()
 
 
+def _edge_orders(case, rng):
+    """Orders at the edges of the binning's domain, L=32: (orders, chunk)."""
+    side_buy, price, qty = _random_orders(rng, 4, 48, 32)
+    if case == "one_sided":          # markets 0, 2 all buy; 1, 3 all sell
+        side_buy = np.repeat((np.arange(4) % 2 == 0)[:, None], 48, axis=1)
+    elif case == "edge_ticks":       # only ticks 0 and L - 1
+        price = np.where(rng.random((4, 48)) < 0.5, 0, 31).astype(np.int32)
+    elif case == "whale":            # whale-size quantities among small ones
+        qty = np.where(rng.random((4, 48)) < 0.5, 32.0, qty).astype(np.float32)
+    elif case == "ragged_chunk":     # A=200 is not a multiple of 64
+        return _random_orders(rng, 4, 200, 32), 64
+    return (side_buy, price, qty), 16
+
+
+def _assert_matches_scatter(orders, L, xp, agent_chunk):
+    side_buy, price, qty = orders
+    want = _bin_orders_scatter_ref(side_buy, price, qty, price.shape[0], L)
+    got = bin_orders_onehot(xp.asarray(side_buy), xp.asarray(price),
+                            xp.asarray(qty), L, xp, agent_chunk=agent_chunk)
+    for g, w in zip(got, want):
+        g = np.asarray(g)
+        assert g.dtype == np.float32 and g.shape == w.shape
+        assert (g == w).all()
+
+
+def _xp(name):
+    import jax.numpy as jnp
+
+    return {"numpy": np, "jnp": jnp}[name]
+
+
+@pytest.mark.parametrize("xp", ["numpy", "jnp"])
+@pytest.mark.parametrize("case", ["one_sided", "edge_ticks", "whale",
+                                  "ragged_chunk"])
+def test_onehot_edge_orders_match_scatter(case, xp):
+    rng = np.random.default_rng(23)
+    orders, agent_chunk = _edge_orders(case, rng)
+    _assert_matches_scatter(orders, 32, _xp(xp), agent_chunk)
+
+
+@pytest.mark.parametrize("xp", ["numpy", "jnp"])
+@pytest.mark.parametrize("agent_chunk", [64, 128, None])
+@pytest.mark.parametrize("A", [256, 1024])
+def test_onehot_cell_shapes_match_scatter(A, agent_chunk, xp):
+    """The benchmark cells' widths (L=128; A=256 and A=1024), cut to a few
+    markets, at every agent chunk the tile sweep tries."""
+    rng = np.random.default_rng(A + (agent_chunk or 0))
+    orders = _random_orders(rng, 3, A, 128)
+    _assert_matches_scatter(orders, 128, _xp(xp), agent_chunk)
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs nested in its params."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            inner = getattr(v, "jaxpr", v)
+            if hasattr(inner, "eqns"):
+                yield from _eqns(inner)
+
+
+def test_onehot_is_one_level_major_contraction_per_agent_chunk():
+    """Buy and sell share one MXU pass per agent chunk, over a level-major
+    [M, L, Ac] one-hot selected in f32: the two-pass, agent-major form
+    (two matvecs re-latching one [Ac, L] one-hot) must not come back."""
+    import jax
+    import jax.numpy as jnp
+
+    M, A, L, ac = 3, 256, 128, 128
+    closed = jax.make_jaxpr(
+        lambda s, p, q: bin_orders_onehot(s, p, q, L, jnp, agent_chunk=ac))(
+        jnp.zeros((M, A), bool), jnp.zeros((M, A), jnp.int32),
+        jnp.zeros((M, A), jnp.float32))
+    eqns = list(_eqns(closed.jaxpr))
+    dots = [e for e in eqns if e.primitive.name == "dot_general"]
+    assert len(dots) == A // ac
+    for dot in dots:
+        lhs, rhs = (v.aval for v in dot.invars)
+        assert lhs.shape == (M, 2, ac) and lhs.dtype == jnp.float32
+        assert rhs.shape == (M, L, ac) and rhs.dtype == jnp.float32
+        # contract the agent axis, last in both; batch over markets
+        assert dot.params["dimension_numbers"] == (((2,), (2,)), ((0,), (0,)))
+    # the one-hot is selected in f32, never converted from its mask
+    assert not any(e.primitive.name == "convert_element_type"
+                   and e.outvars[0].aval.ndim == 3 for e in eqns)
+
+
 def test_onehot_matches_scatter_jax():
     import jax.numpy as jnp
 

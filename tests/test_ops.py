@@ -270,5 +270,5 @@ def test_estimate_vmem_bytes_scales_with_tile():
     a = tune.estimate_vmem_bytes(small, num_levels=32, num_agents=256)
     b = tune.estimate_vmem_bytes(big, num_levels=32, num_agents=256)
     assert 0 < a < b
-    # dominated by the [MB, Ac, L] one-hot intermediate
+    # dominated by the [MB, L, Ac] one-hot intermediate
     assert a >= 4 * 8 * 64 * 32
