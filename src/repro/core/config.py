@@ -42,6 +42,20 @@ CH_MKT = 2
 CH_QTY = 3
 CH_SHOCK = 4
 
+#: The widest price grid the chunk kernels are compiled for (L=4096 in
+#: ``tests/test_tpu_compile.py``); wider grids are refused.
+MAX_LEVELS = 4096
+
+
+def check_num_levels(L: int) -> None:
+    """Refuse a grid the kernels cannot run: ``L`` must be a power of two
+    in ``[4, MAX_LEVELS]``."""
+    if L < 4 or (L & (L - 1)) != 0:
+        raise ValueError(f"num_levels must be a power of two >= 4, got {L}")
+    if L > MAX_LEVELS:
+        raise ValueError(f"num_levels={L} is above {MAX_LEVELS}, the widest "
+                         "grid the chunk kernels are compiled for")
+
 
 @dataclasses.dataclass(frozen=True)
 class MarketConfig:
@@ -103,11 +117,7 @@ class MarketConfig:
     initial_spread: int = 2        # opening bid at L/2 - spread/2 ... ask at +
 
     def __post_init__(self):
-        L = self.num_levels
-        if L < 4 or (L & (L - 1)) != 0:
-            raise ValueError(f"num_levels must be a power of two >= 4, got {L}")
-        if L > 1024:
-            raise ValueError("num_levels > 1024 requires tiling (paper §V)")
+        check_num_levels(self.num_levels)
         mix_total = (self.alpha_maker + self.alpha_momentum
                      + self.alpha_fundamentalist + self.alpha_whale
                      + self.alpha_hft + self.alpha_informed

@@ -41,6 +41,7 @@ import numpy as np
 from repro.core.config import (
     MarketConfig,
     assign_agent_types,
+    check_num_levels,
     scenario_config,
     seed_books,
 )
@@ -486,10 +487,7 @@ class EnsembleSpec:
     # ---- validation (the scalar path's __post_init__, per market) ----
     def validate(self) -> None:
         M, A, L = self.num_markets, self.num_agents, self.num_levels
-        if L < 4 or (L & (L - 1)) != 0:
-            raise ValueError(f"num_levels must be a power of two >= 4, got {L}")
-        if L > 1024:
-            raise ValueError("num_levels > 1024 requires tiling (paper §V)")
+        check_num_levels(L)
         p = self.params.to_numpy()
         for f in MarketParams._fields:
             arr = np.asarray(getattr(p, f))

@@ -429,8 +429,14 @@ class Engine:
             registry = MetricsRegistry()
         sid = next(self._opened)
         with span("kinetic.open", session=sid, markets=spec.num_markets):
-            with span("kinetic.open.runner"):
+            with span("kinetic.open.runner", levels=spec.num_levels) as sp:
                 runner = self._runner(spec, max(1, chunk))
+                tile = getattr(runner, "tile", None)
+                if tile is not None:  # Pallas engines: the resolved tile
+                    sp.annotate(
+                        mb=tile.mb,
+                        agent_chunk=tile.agent_chunk or spec.num_agents,
+                        level_tile=tile.level_tile or spec.num_levels)
             return Session(self, spec, runner, metrics=registry, sid=sid)
 
     def warm(self, specs, *, chunk_sizes=None, include_step: bool = True):
@@ -516,6 +522,7 @@ class Session:
 
                 metrics.gauge("tile_mb", tile.mb)
                 metrics.gauge("tile_agent_chunk", tile.agent_chunk)
+                metrics.gauge("tile_level_tile", tile.level_tile)
                 metrics.gauge("autotune_vmem_bytes", tune.estimate_vmem_bytes(
                     tile, spec.num_levels, spec.num_agents, runner.chunk))
 

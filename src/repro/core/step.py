@@ -51,7 +51,8 @@ def initial_state(cfg, xp) -> MarketState:
     return MarketState(bid=bid, ask=ask, last_price=ones * m0, prev_mid=ones * m0)
 
 
-def bin_orders_onehot(side_buy, price, qty, L, xp, agent_chunk=None):
+def bin_orders_onehot(side_buy, price, qty, L, xp, agent_chunk=None,
+                      level_tile=None):
     """Order aggregation as a one-hot contraction (TPU/MXU idiom).
 
     BUY[m, l] = sum_a qty[m, a] * [price[m, a] == l & side_buy[m, a]]
@@ -69,26 +70,37 @@ def bin_orders_onehot(side_buy, price, qty, L, xp, agent_chunk=None):
     non-contracting dimension Mosaic needs to lower a batched matmul; it
     latches the level-major one-hot in its transposed mode.
 
-    ``agent_chunk`` bounds the one-hot intermediate (the dominant VMEM term
-    inside the persistent kernel) by accumulating the contraction over
-    static slices of the agent axis. Because every partial sum is an exact
-    integer in f32, the result is bitwise-identical for any chunking. Agent
-    slices are cut with plain slices and ``expand_dims``, never mixed
-    indexing, which Mosaic would see as a gather.
+    ``agent_chunk`` and ``level_tile`` bound the one-hot intermediate (the
+    dominant VMEM term inside the persistent kernel) to ``[M, Lt, Ac]``:
+    the contraction is accumulated over static slices of the agent axis,
+    once per tile of ``level_tile`` consecutive levels, and the tiles'
+    ``[M, 2, Lt]`` results are joined along the lanes. Because every
+    partial sum is an exact integer in f32, the result is bitwise-identical
+    for any chunking and tiling; ``level_tile >= L`` (or ``None``) traces
+    the single-tile code. Agent slices are cut with plain slices and
+    ``expand_dims``, never mixed indexing, which Mosaic would see as a
+    gather.
     """
     f32 = xp.float32
-    levels = xp.expand_dims(xp.arange(L, dtype=xp.int32), -1)     # [L, 1]
     q = xp.stack([qty * side_buy.astype(f32),
                   qty * (~side_buy).astype(f32)], axis=1)         # [M, 2, A]
     A = price.shape[-1]
     step = A if not agent_chunk else min(agent_chunk, A)
-    acc = None
-    for a0 in range(0, A, step):
-        a1 = min(a0 + step, A)
-        onehot = xp.where(xp.expand_dims(price[:, a0:a1], 1) == levels,
-                          f32(1.0), f32(0.0))                     # [M, L, Ac]
-        part = xp.einsum("mka,mla->mkl", q[:, :, a0:a1], onehot)  # [M, 2, L]
-        acc = part if acc is None else acc + part
+    lt = L if not level_tile else min(level_tile, L)
+    tiles = []
+    for l0 in range(0, L, lt):
+        levels = xp.expand_dims(xp.arange(l0, l0 + lt, dtype=xp.int32),
+                                -1)                               # [Lt, 1]
+        acc = None
+        for a0 in range(0, A, step):
+            a1 = min(a0 + step, A)
+            onehot = xp.where(xp.expand_dims(price[:, a0:a1], 1) == levels,
+                              f32(1.0), f32(0.0))                 # [M, Lt, Ac]
+            part = xp.einsum("mka,mla->mkl", q[:, :, a0:a1],
+                             onehot)                              # [M, 2, Lt]
+            acc = part if acc is None else acc + part
+        tiles.append(acc)
+    acc = tiles[0] if len(tiles) == 1 else xp.concatenate(tiles, axis=-1)
     return acc[:, 0], acc[:, 1]
 
 
@@ -201,6 +213,7 @@ def simulate_step(
     ext_buy=None,
     ext_ask=None,
     agent_chunk=None,
+    level_tile=None,
     params: Optional[MarketParams] = None,
     atype=None,
     seed=None,
@@ -222,8 +235,9 @@ def simulate_step(
     no-op (exact-integer f32 adds), so gated injection never perturbs the
     stream; ``None`` keeps pre-session traces byte-identical.
 
-    ``agent_chunk`` is forwarded to the default one-hot binning (a pure
-    VMEM-footprint knob — bitwise-invisible; see :func:`bin_orders_onehot`).
+    ``agent_chunk`` and ``level_tile`` are forwarded to the default one-hot
+    binning (pure VMEM-footprint knobs — bitwise-invisible; see
+    :func:`bin_orders_onehot`).
     ``atype`` optionally carries the precomputed (step-invariant) per-market
     agent-type lattice so loop drivers hoist it out of the step loop.
     ``seed`` optionally overrides the counter-RNG seed at runtime (traced
@@ -247,7 +261,8 @@ def simulate_step(
         params = params_mod.scalar_params(cfg, xp)
     if bin_orders is None:
         bin_orders = lambda s, p, q: bin_orders_onehot(
-            s, p, q, cfg.num_levels, xp, agent_chunk=agent_chunk)
+            s, p, q, cfg.num_levels, xp, agent_chunk=agent_chunk,
+            level_tile=level_tile)
     f32 = xp.float32
 
     # Scenario overlay (before quoting: the withdrawal moves the mid too).

@@ -9,15 +9,20 @@ under-utilization on TPU. This module replaces that policy:
     exact. The kernel wrappers pad the market axis with benign zero rows
     (markets are row-independent, so real rows are bitwise unaffected) and
     slice the outputs back — M=63 runs the identical tile shape as M=64.
-  * :func:`autotune_tile` optionally *sweeps* (MB, agent-chunk) candidates
-    by compiling and timing each on first use, caching the winner per
-    ``(device-kind, L, A, chunk)`` so every engine/runner built later in
-    the process reuses the measured choice without re-sweeping.
+  * :func:`autotune_tile` optionally *sweeps* (MB, agent-chunk,
+    level-tile) candidates by compiling and timing each on first use,
+    caching the winner per ``(device-kind, L, A, chunk)`` so every
+    engine/runner built later in the process reuses the measured choice
+    without re-sweeping. Each candidate takes the widest level tile at
+    which its :func:`estimate_vmem_bytes` fits :data:`VMEM_BUDGET_BYTES`,
+    and one that fits at none is pruned, all before any compile.
 
-The agent-chunk knob bounds the one-hot binning's level-major
-[MB, L, Ac] VMEM intermediate (see ``bin_orders_onehot``); f32
-exact-integer adds make the chunked accumulation bitwise-identical for
-any chunk size.
+The agent-chunk and level-tile knobs bound the one-hot binning's
+level-major [MB, Lt, Ac] VMEM intermediate (see ``bin_orders_onehot``);
+f32 exact-integer adds make the chunked, tiled accumulation
+bitwise-identical for any chunk and tile. A level tile is taken only
+where the untiled one-hot would not fit the budget, so the L=128
+ensembles (A up to 1024) sweep the (MB, agent-chunk) grid untiled.
 """
 from __future__ import annotations
 
@@ -28,6 +33,24 @@ from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 from repro.ops.metrics import span
 
 SUBLANES = 8  # TPU f32 sublane count — tiles want MB ≡ 0 (mod 8)
+LANES = 128   # TPU lane count — a one-hot's agent axis pads to it
+
+#: The tile policy's VMEM budget per grid cell: Mosaic's default scoped
+#: VMEM limit on a TPU v5e core. Every tile the policy picks fits it by
+#: :func:`estimate_vmem_bytes`.
+VMEM_BUDGET_BYTES = 16 << 20
+
+#: [MB, L] f32 values one step of the clearing kernel holds at once (books,
+#: incoming orders, the two scans, clearing and the carried state). Fitted
+#: above the scoped VMEM Mosaic allocates for v5e at L=4096: 4.5-5 MiB at
+#: MB=8 and 7-8 MiB at MB=16, whatever the agent chunk (PERF.md).
+LEVEL_TEMPS = 40
+
+#: An agent chunk narrower than a lane costs NARROW_TEMPS * MB * MB * L f32
+#: more, whatever the level tile. Fitted above Mosaic's allocation for v5e
+#: at L=4096 and Ac=64: about 5 MiB more at MB=8 and 20-25 MiB more at
+#: MB=16 than the same tile at a whole lane (PERF.md).
+NARROW_TEMPS = 6
 
 #: Winner cache for the timed sweep: (device_kind, L, A, chunk) -> TileChoice.
 _TUNE_CACHE: Dict[Tuple[str, int, int, int], "TileChoice"] = {}
@@ -58,18 +81,46 @@ def is_oom_error(exc: BaseException) -> bool:
 
 def estimate_vmem_bytes(tile: "TileChoice", num_levels: int,
                         num_agents: int, chunk: int = 1) -> int:
-    """Rough per-grid-cell VMEM working set of the clearing kernel, bytes.
+    """Per-grid-cell VMEM working set of the clearing kernel, bytes.
 
-    Dominated by the level-major [MB, L, Ac] one-hot binning intermediate,
-    plus the resident books/profiles (6 × [MB, L]) and the per-chunk output
-    paths (3 × [MB, chunk]); all f32. An estimate for dashboards and tile-pressure
-    gauges, not a lowering-accurate allocator model.
+    The level-major [MB, Lt, Ac] one-hot binning intermediate (Lt the
+    level tile, Ac the agent chunk padded to whole lanes), plus the step's
+    ``LEVEL_TEMPS`` level-wide [MB, L] values, ``NARROW_TEMPS`` · MB more
+    of them where the agent chunk is narrower than a lane, and the
+    per-chunk output paths (3 × [MB, chunk]); all f32. Fitted to stay
+    above what Mosaic allocates on a v5e, not an allocator model: Mosaic
+    streams a lane-aligned one-hot into the MXU without holding it whole,
+    so the estimate over-counts those tiles.
     """
     ac = tile.agent_chunk or max(1, num_agents)
-    onehot = tile.mb * ac * num_levels
-    books = 6 * tile.mb * num_levels
+    lt = min(tile.level_tile or num_levels, num_levels)
+    onehot = tile.mb * lt * pad_to_multiple(ac, LANES)
+    levels = LEVEL_TEMPS * tile.mb * num_levels
+    if ac < LANES:
+        levels += NARROW_TEMPS * tile.mb * tile.mb * num_levels
     paths = 3 * tile.mb * max(1, chunk)
-    return 4 * (onehot + books + paths)
+    return 4 * (onehot + levels + paths)
+
+
+def fits(tile: "TileChoice", num_levels: int, num_agents: int,
+         chunk: int = 1, budget: int = VMEM_BUDGET_BYTES) -> bool:
+    return estimate_vmem_bytes(tile, num_levels, num_agents, chunk) <= budget
+
+
+def fitting_level_tile(tile: "TileChoice", num_levels: int,
+                       num_agents: int, chunk: int = 1) -> Optional[int]:
+    """The widest level tile at which ``tile`` fits the budget: ``None``
+    (all of L) where the untiled one-hot fits, else the widest power of
+    two of at least a lane's width that does, else one lane's width (a
+    tile that fits at no level tile, such as MB=16 with a 64-wide agent
+    chunk at L=4096)."""
+    lt = None
+    while not fits(tile._replace(level_tile=lt), num_levels, num_agents,
+                   chunk):
+        lt = (lt or num_levels) // 2
+        if lt <= LANES:
+            return min(LANES, num_levels)
+    return lt
 
 
 def sweep_reports() -> Tuple["SweepReport", ...]:
@@ -81,11 +132,13 @@ def last_sweep_report() -> Optional["SweepReport"]:
 
 
 class TileChoice(NamedTuple):
-    """A resolved kernel tiling: grid tile, padded M, agent-chunk length."""
+    """A resolved kernel tiling: grid tile, padded M, agent-chunk length,
+    level tile."""
 
     mb: int                        # markets per grid cell (sublane axis)
     m_padded: int                  # M rounded up to a multiple of mb
     agent_chunk: Optional[int]     # one-hot binning chunk (None = all of A)
+    level_tile: Optional[int] = None   # one-hot level tile (None = all of L)
 
     @property
     def grid(self) -> int:
@@ -106,7 +159,9 @@ def auto_tile(num_markets: int, num_agents: int = 0,
     """Heuristic sublane-aligned tile: pad M up instead of shrinking MB.
 
     Any M maps to MB=``target`` with ``ceil(M/target)`` grid cells — the
-    tile *shape* depends only on ``target``, never on M's divisors.
+    tile *shape* depends only on ``target``, never on M's divisors. The
+    runner gives it the widest level tile that fits the VMEM budget
+    (:func:`fitting_level_tile`), so the heuristic fits at any L.
     """
     mb = max(1, target)
     return TileChoice(mb=mb, m_padded=pad_to_multiple(max(1, num_markets), mb),
@@ -115,12 +170,17 @@ def auto_tile(num_markets: int, num_agents: int = 0,
 
 def candidate_tiles(num_markets: int, num_agents: int,
                     target: int = SUBLANES,
-                    agent_chunk: Optional[int] = ...) -> List[TileChoice]:
+                    agent_chunk: Optional[int] = ...,
+                    num_levels: Optional[int] = None,
+                    chunk: int = 1) -> List[TileChoice]:
     """The (MB, agent-chunk) sweep grid for :func:`autotune_tile`.
 
     An explicit ``agent_chunk`` (including ``None`` = unchunked) pins that
     knob and sweeps MB only — a caller-set VMEM bound must never be
-    overridden by the sweep.
+    overridden by the sweep. Given ``num_levels``, each pair takes its
+    widest fitting level tile (:func:`fitting_level_tile`), and a pair
+    that fits the VMEM budget at none is left out, so nothing over the
+    budget is ever compiled.
     """
     mbs = sorted({target, 2 * target})
     if agent_chunk is not ...:
@@ -131,9 +191,15 @@ def candidate_tiles(num_markets: int, num_agents: int,
     out = []
     for mb in mbs:
         for ac in acs:
-            out.append(TileChoice(
+            tile = TileChoice(
                 mb=mb, m_padded=pad_to_multiple(max(1, num_markets), mb),
-                agent_chunk=None if ac >= num_agents else ac))
+                agent_chunk=None if ac >= num_agents else ac)
+            if num_levels is not None:
+                tile = tile._replace(level_tile=fitting_level_tile(
+                    tile, num_levels, num_agents, chunk))
+                if not fits(tile, num_levels, num_agents, chunk):
+                    continue
+            out.append(tile)
     # dedup while keeping sweep order deterministic
     seen, uniq = set(), []
     for c in out:
@@ -159,23 +225,27 @@ def autotune_tile(key: Tuple,
                   time_candidate: Callable[[TileChoice], float],
                   cands: List[TileChoice],
                   fallback: Optional[TileChoice] = None,
-                  num_markets: Optional[int] = None) -> TileChoice:
+                  num_markets: Optional[int] = None,
+                  pruned: int = 0) -> TileChoice:
     """Measure each candidate once (first compile), cache the winner.
 
     ``time_candidate`` compiles + runs one representative chunk call and
-    returns its wall time. An out-of-memory-shaped failure
+    returns its wall time. ``pruned`` (the candidates
+    :func:`candidate_tiles` left out as over the VMEM budget) is recorded
+    on the sweep's span. An out-of-memory-shaped failure
     (:func:`is_oom_error`: the tile does not fit) disqualifies the
     candidate; any other exception, such as a kernel the compiler rejects,
     propagates. If every candidate runs out of memory, ``fallback`` (the
     caller's heuristic choice) is used.
     Cached winners are re-padded for the caller's ``num_markets`` — only
-    (mb, agent_chunk) is reused across ensemble sizes.
+    (mb, agent_chunk, level_tile) is reused across ensemble sizes.
     """
     cached = _TUNE_CACHE.get(key)
     if cached is None:
         best, best_t = None, float("inf")
         failures = []
-        with span("kinetic.open.autotune", candidates=len(cands)):
+        with span("kinetic.open.autotune", candidates=len(cands),
+                  pruned=pruned):
             for cand in cands:
                 try:
                     t = time_candidate(cand)

@@ -4,7 +4,8 @@ GPU original (paper §III): one CUDA block per market, LOB in ``__shared__``
 memory for all S steps, atomicAdd order binning, Hillis–Steele scans,
 tournament argmax.
 
-TPU adaptation (DESIGN.md §2): one Pallas grid cell per *tile* of MB markets.
+TPU adaptation (PERF.md, section 3): one Pallas grid cell per *tile* of MB
+markets.
 The entire S-step loop runs inside the kernel body; the books live in VMEM
 (registers/VMEM values carried through ``lax.fori_loop``) and touch HBM only
 at kernel entry/exit — HBM traffic is Θ(M·L), independent of S, exactly the
@@ -17,18 +18,24 @@ Block/tile layout: markets on sublanes (MB a multiple of 8 — the chunk
 entries *pad* the market axis to a tile multiple instead of shrinking MB, so
 prime/odd M keeps full sublane tiles; see :mod:`repro.kernels.autotune`),
 price ticks on lanes (L multiple of 128 native; smaller L still correct,
-just padded by the compiler). VMEM working set per grid cell ≈
-``7·MB·L + MB·L·Ac (the level-major [MB, L, Ac] one-hot binning, Ac =
-agent_chunk ≤ A) + 2·MB·S`` f32 for path outputs, plus a negligible
-``12·MB`` term for the per-market parameter columns (the
-:class:`repro.core.params.MarketParams` operands, one ``(MB, 1)`` block
-each) — padding adds only whole-tile rows, so the
+just padded by the compiler; up to ``MAX_LEVELS`` = 4096, where the
+Hillis–Steele scans take 12 stages of whole-vreg lane shifts). VMEM
+working set per grid cell ≈ ``K·MB·L`` for the step's level-wide values
+(K ≈ 40, fitted to Mosaic's allocation at L=4096) ``+ MB·Lt·Ac`` (the
+level-major [MB, Lt, Ac] one-hot binning, Lt = level_tile ≤ L, Ac =
+agent_chunk ≤ A, padded to whole lanes; an agent chunk narrower than a
+lane adds ``6·MB·MB·L``) ``+ 3·MB·chunk`` f32 for path
+outputs, plus a negligible ``22·MB`` term for the per-market parameter
+columns (the :class:`repro.core.params.MarketParams` operands, one
+``(MB, 1)`` block each) — padding adds only whole-tile rows, so the
 padded-tile term is the same ``MB·(...)`` budget with ``grid =
-ceil(M/MB)`` cells. In ``stats_only`` mode the ``2·MB·S`` path term is
-replaced by a constant ``6·MB`` statistics-accumulator term
+ceil(M/MB)`` cells. :func:`repro.kernels.autotune.estimate_vmem_bytes`
+is this formula; the tile policy keeps every tile under Mosaic's default
+scoped limit by it. In ``stats_only`` mode the ``3·MB·chunk`` path
+term is replaced by a constant ``6·MB`` statistics-accumulator term
 (count/Σmid/Σmid²/min/max/Σvolume), making both the VMEM footprint and the
-HBM output traffic independent of the chunk length — see EXPERIMENTS.md
-§Perf for the measured budget.
+HBM output traffic independent of the chunk length — see PERF.md
+(sections 5 and 6) for the measured budget.
 
 Scenario engine: archetype mixtures and scenario overlays (flash-crash
 shock, volatility regimes, book seeding) are static ``cfg`` fields dispatched
@@ -212,7 +219,7 @@ def _chunk_kernel_body(
     peer_ref,
     *refs,
     cfg, mb: int, chunk: int,
-    agent_chunk: Optional[int], stats_only: bool,
+    agent_chunk: Optional[int], level_tile: Optional[int], stats_only: bool,
 ):
     """Session variant of the persistent scheduler: a fixed ``chunk``-length
     trace that serves *any* requested step count and *any* scenario mixture.
@@ -274,7 +281,8 @@ def _chunk_kernel_body(
         new_state, out = simulate_step(
             cfg, state, step0 + s, market_ids, jnp, bin_orders=None,
             scan=KERNEL_SCAN, ext_buy=eb, ext_ask=ea, agent_chunk=agent_chunk,
-            params=params, atype=atype, peer_mid=peer_mid,
+            level_tile=level_tile, params=params, atype=atype,
+            peer_mid=peer_mid,
         )
         # Steps past n_valid are computed but discarded — the carried state
         # only advances while active.
@@ -336,7 +344,7 @@ def kinetic_clearing_chunk(
     ext_buy: jax.Array, ext_ask: jax.Array,
     *, cfg, chunk: int, mb: int = 8,
     interpret: bool = False, market_ids: Optional[jax.Array] = None,
-    agent_chunk: Optional[int] = None,
+    agent_chunk: Optional[int] = None, level_tile: Optional[int] = None,
     params: Optional[MarketParams] = None,
     peer_mid: Optional[jax.Array] = None,
     stats: Optional[stats_mod.MarketStats] = None, stats_only: bool = False,
@@ -366,6 +374,9 @@ def kinetic_clearing_chunk(
     Sharded callers must pass the column explicitly (see the ring halo
     exchange in :mod:`repro.kernels.ops`), since a cross-shard peer is not
     addressable by a local gather.
+
+    ``agent_chunk`` and ``level_tile`` tile the order binning's one-hot
+    (bitwise-invisible; see :func:`repro.core.step.bin_orders_onehot`).
 
     Returns ``(bid, ask, last, pmid, price_path[M, chunk],
     volume_path[M, chunk], mid_path[M, chunk])``, or with
@@ -432,7 +443,8 @@ def kinetic_clearing_chunk(
 
     out = pallas_call(
         functools.partial(_chunk_kernel_body, cfg=cfg, mb=mb, chunk=chunk,
-                          agent_chunk=agent_chunk, stats_only=stats_only),
+                          agent_chunk=agent_chunk, level_tile=level_tile,
+                          stats_only=stats_only),
         name="kinetic_clearing_step" if chunk == 1
         else "kinetic_clearing_chunk",
         grid=grid,

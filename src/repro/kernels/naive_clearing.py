@@ -125,7 +125,7 @@ def _chunk_step_kernel_body(
     bid_ref, ask_ref, last_ref, pmid_ref, ext_buy_ref, ext_ask_ref,
     peer_ref,
     *refs,
-    cfg, mb: int, agent_chunk: Optional[int],
+    cfg, mb: int, agent_chunk: Optional[int], level_tile: Optional[int],
 ):
     """Per-step kernel with external-order inputs (Session API variant).
 
@@ -148,7 +148,8 @@ def _chunk_step_kernel_body(
     new_state, out = simulate_step(
         cfg, state, s, market_ids, jnp, scan=KERNEL_SCAN,
         ext_buy=ext_buy_ref[...], ext_ask=ext_ask_ref[...],
-        agent_chunk=agent_chunk, params=params, peer_mid=peer_ref[...],
+        agent_chunk=agent_chunk, level_tile=level_tile, params=params,
+        peer_mid=peer_ref[...],
     )
     out_bid_ref[...] = new_state.bid
     out_ask_ref[...] = new_state.ask
@@ -165,7 +166,7 @@ def naive_clearing_chunk(
     ext_buy: jax.Array, ext_ask: jax.Array,
     *, cfg, chunk: int, mb: int = 8,
     interpret: bool = False, market_ids: Optional[jax.Array] = None,
-    agent_chunk: Optional[int] = None,
+    agent_chunk: Optional[int] = None, level_tile: Optional[int] = None,
     params: Optional[MarketParams] = None,
     peer_mid: Optional[jax.Array] = None,
     stats: Optional[stats_mod.MarketStats] = None, stats_only: bool = False,
@@ -217,7 +218,7 @@ def naive_clearing_chunk(
     )
     step_call = pallas_call(
         functools.partial(_chunk_step_kernel_body, cfg=cfg, mb=mb,
-                          agent_chunk=agent_chunk),
+                          agent_chunk=agent_chunk, level_tile=level_tile),
         name="naive_clearing_step",
         grid=grid,
         in_specs=[step_spec, scalar_spec, book_spec, book_spec, scalar_spec,

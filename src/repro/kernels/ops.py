@@ -27,13 +27,14 @@ Scaling knobs (Engine backend_opts, all composable):
   * ``stats_only=True`` — replace the per-step path outputs with in-kernel
     running statistics (see :mod:`repro.core.stats`): the kernel's HBM
     output traffic drops from Θ(M·chunk) to Θ(M), independent of horizon.
-  * ``mb=`` / ``agent_chunk=`` / ``autotune=`` — tile selection. By default
-    the market axis is padded to sublane-aligned MB=8 tiles
-    (:func:`repro.kernels.autotune.auto_tile`); ``autotune=True`` (or
-    ``"auto"``, which sweeps only when lowering via Mosaic on a TPU)
-    times (MB, agent-chunk) candidates on first compile and caches the
-    winner per ``(device-kind, L, A, chunk)`` for every engine in the
-    process.
+  * ``mb=`` / ``agent_chunk=`` / ``autotune=`` — tile selection. By
+    default the market axis is padded to sublane-aligned MB=8 tiles
+    (:func:`repro.kernels.autotune.auto_tile`), with the widest level tile
+    that fits the VMEM budget; ``autotune=True`` (or ``"auto"``, which
+    sweeps only when lowering via Mosaic on a TPU) times (MB, agent-chunk)
+    candidates, each at its widest fitting level tile, on first compile
+    and caches the winner per ``(device-kind, L, A, chunk)`` for every
+    engine in the process.
 """
 from __future__ import annotations
 
@@ -150,6 +151,7 @@ class PallasChunkRunner(session.ChunkRunner):
         kernel_kwargs = dict(cfg=spec, chunk=chunk, mb=self.tile.mb,
                              interpret=self.interpret,
                              agent_chunk=self.tile.agent_chunk,
+                             level_tile=self.tile.level_tile,
                              stats_only=stats_only)
 
         if self._mesh is None:
@@ -252,16 +254,23 @@ class PallasChunkRunner(session.ChunkRunner):
     # ---- tile selection ----
     def _resolve_tile(self, kernel_chunk_fn, spec, m_local, mb, agent_chunk,
                       autotune) -> tune.TileChoice:
+        L, A = spec.num_levels, spec.num_agents
+
+        def fitted(tile):
+            return tile._replace(level_tile=tune.fitting_level_tile(
+                tile, L, A, self.chunk))
+
         if mb is not None:
-            return tune.TileChoice(
+            return fitted(tune.TileChoice(
                 mb=mb, m_padded=tune.pad_to_multiple(m_local, mb),
                 agent_chunk=(agent_chunk if agent_chunk is not None
-                             else tune.default_agent_chunk(spec.num_agents)))
+                             else tune.default_agent_chunk(A))))
         sweep = autotune is True or (autotune == "auto"
                                      and not self.interpret)
-        heuristic = tune.auto_tile(m_local, spec.num_agents)
+        heuristic = tune.auto_tile(m_local, A)
         if agent_chunk is not None:
             heuristic = heuristic._replace(agent_chunk=agent_chunk)
+        heuristic = fitted(heuristic)
         if not sweep:
             return heuristic
 
@@ -281,6 +290,7 @@ class PallasChunkRunner(session.ChunkRunner):
                     bid, bid, scalars, scalars, step0, nv, bid, bid,
                     cfg=spec, chunk=self.chunk, mb=choice.mb,
                     interpret=self.interpret, agent_chunk=choice.agent_chunk,
+                    level_tile=choice.level_tile,
                     params=zp, stats=st, stats_only=self.stats_only)
 
             return tune.time_call(fn, jax.block_until_ready)
@@ -289,14 +299,15 @@ class PallasChunkRunner(session.ChunkRunner):
         # kernel configurations (family / stats mode) never share a measured
         # winner.
         key = tune.tune_key(
-            spec.num_levels, spec.num_agents, self.chunk,
-            kernel=kernel_chunk_fn.__name__,
+            L, A, self.chunk, kernel=kernel_chunk_fn.__name__,
             stats_only=self.stats_only, agent_chunk=agent_chunk)
-        cands = tune.candidate_tiles(
-            m_local, spec.num_agents,
-            agent_chunk=agent_chunk if agent_chunk is not None else ...)
+        pin = agent_chunk if agent_chunk is not None else ...
+        cands = tune.candidate_tiles(m_local, A, agent_chunk=pin,
+                                     num_levels=L, chunk=self.chunk)
+        pruned = len(tune.candidate_tiles(m_local, A, agent_chunk=pin))
         return tune.autotune_tile(key, time_candidate, cands,
-                                  fallback=heuristic, num_markets=m_local)
+                                  fallback=heuristic, num_markets=m_local,
+                                  pruned=pruned - len(cands))
 
     # ---- placement hooks (sharded state stays sharded across snapshots) ----
     def init_state(self, spec: EnsembleSpec) -> MarketState:

@@ -17,9 +17,15 @@ duration. The program's spans (``kbench/spans.py`` reduces them):
                                  (``session``, a per-engine counter;
                                  ``markets``)
     ``kinetic.open.runner``      the runner lookup: cache hit or factory
+                                 (``levels``; on the Pallas engines the
+                                 resolved tile: ``mb``, ``agent_chunk``,
+                                 ``level_tile``, each the whole axis
+                                 where untiled)
       ``kinetic.open.autotune``  a Pallas tile sweep (cache misses only;
-                                 ``candidates``); under ``kinetic.step``
-                                 when a first step builds its runner
+                                 ``candidates``, ``pruned``: those over
+                                 the VMEM budget, never compiled); under
+                                 ``kinetic.step`` when a first step builds
+                                 its runner
     ``kinetic.open.place``       state, params, aux and stats placed on the
                                  device (``bytes``)
   ``kinetic.step``               ``Session.step``
@@ -59,7 +65,7 @@ per-session ``open(spec, metrics=False)``). The session records:
             ``restore_seconds``
   gauges    ``chunk``, ``num_markets``, and on the Pallas engines the
             autotune tile pressure: ``autotune_vmem_bytes``, ``tile_mb``,
-            ``tile_agent_chunk``
+            ``tile_agent_chunk``, ``tile_level_tile``
 
 The registry is generic — any consumer may ``inc``/``observe``/``gauge``
 additional series. The serving gateway (:mod:`repro.serve`) records:

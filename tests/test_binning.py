@@ -304,3 +304,48 @@ def test_onehot_agent_chunking_bitwise(agent_chunk):
                             agent_chunk=agent_chunk)
     assert (got[0] == want[0]).all()
     assert (got[1] == want[1]).all()
+
+
+@pytest.mark.parametrize("xp", ["numpy", "jnp"])
+@pytest.mark.parametrize("agent_chunk", [None, 128])
+@pytest.mark.parametrize("level_tile", [128, 512, 2048, 4096, None])
+@pytest.mark.parametrize("case", ["random", "edge_ticks"])
+def test_onehot_level_tiling_bitwise_on_4096_levels(case, level_tile,
+                                                    agent_chunk, xp):
+    """Level-tiled against untiled binning at L=4096 (the widest grid),
+    and both against the scatter reference; ``edge_ticks`` puts every
+    order on level 0 or L - 1, where marketable orders and whale sweeps
+    land."""
+    L = 4096
+    rng = np.random.default_rng(4096 + (level_tile or 0))
+    side_buy, price, qty = _random_orders(rng, 3, 256, L, q_max=32)
+    if case == "edge_ticks":
+        price = np.where(rng.random(price.shape) < 0.5, 0,
+                         L - 1).astype(np.int32)
+    x = _xp(xp)
+    want = bin_orders_onehot(x.asarray(side_buy), x.asarray(price),
+                             x.asarray(qty), L, x)
+    got = bin_orders_onehot(x.asarray(side_buy), x.asarray(price),
+                            x.asarray(qty), L, x, agent_chunk=agent_chunk,
+                            level_tile=level_tile)
+    ref = _bin_orders_scatter_ref(side_buy, price, qty, 3, L)
+    for g, w, r in zip(got, want, ref):
+        g, w = np.asarray(g), np.asarray(w)
+        assert g.dtype == np.float32 and g.shape == (3, L)
+        assert (g == w).all() and (g == r).all()
+
+
+def test_untiled_level_tile_traces_the_single_tile_code():
+    """``level_tile >= L`` traces exactly the untiled binning."""
+    import jax
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(5)
+    side_buy, price, qty = _random_orders(rng, 2, 256, 128)
+
+    def jaxpr(**kw):
+        return str(jax.make_jaxpr(lambda s, p, q: bin_orders_onehot(
+            s, p, q, 128, jnp, agent_chunk=128, **kw))(side_buy, price, qty))
+
+    assert jaxpr() == jaxpr(level_tile=128) == jaxpr(level_tile=4096)
+    assert jaxpr() != jaxpr(level_tile=64)

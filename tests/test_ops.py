@@ -272,3 +272,15 @@ def test_estimate_vmem_bytes_scales_with_tile():
     assert 0 < a < b
     # dominated by the [MB, L, Ac] one-hot intermediate
     assert a >= 4 * 8 * 64 * 32
+    # A level tile bounds the one-hot to [MB, Lt, Ac]; the level-wide
+    # working set still grows with L.
+    wide = tune.estimate_vmem_bytes(big, num_levels=4096, num_agents=256)
+    tiled = tune.estimate_vmem_bytes(big._replace(level_tile=512),
+                                     num_levels=4096, num_agents=256)
+    assert tiled < wide
+    assert wide - tiled == 4 * 16 * 256 * (4096 - 512)
+    assert tiled > tune.estimate_vmem_bytes(
+        big._replace(level_tile=512), num_levels=2048, num_agents=256)
+    assert tune.estimate_vmem_bytes(big._replace(level_tile=4096), 128,
+                                    256) == tune.estimate_vmem_bytes(
+                                        big, 128, 256)

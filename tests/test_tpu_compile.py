@@ -3,8 +3,9 @@
 Nothing runs here: each test compiles for a *described* v5e chip (no
 device attached), so what the TPU compiler would reject fails on any host,
 at no chip time. Shapes are the paper's Table IV size (M=8192, A=256,
-L=128, chunk=64), plus A=1024; the results themselves are checked on the
-chip by ``chip_smoke.py``.
+L=128, chunk=64), plus A=1024, and the widest grid the engine accepts
+(M=2048, A=256, L=4096) at the tiles the policy picks there; the results
+themselves are checked on the chip by ``chip_smoke.py`` and the benchmark.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU compiler library, so the
@@ -49,10 +50,10 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _spec(num_agents=A):
+def _spec(num_agents=A, num_markets=M, num_levels=L):
     return EnsembleSpec.from_scenarios(
-        ["hft", "whale"], num_markets=M // 2, num_agents=num_agents,
-        num_levels=L, num_steps=CHUNK, alpha_fundamentalist=0.05,
+        ["hft", "whale"], num_markets=num_markets // 2, num_agents=num_agents,
+        num_levels=num_levels, num_steps=CHUNK, alpha_fundamentalist=0.05,
         alpha_arbitrageur=0.05)
 
 
@@ -63,8 +64,8 @@ def _shapes(spec, sharding, stats_only):
     def sds(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
-    m = spec.num_markets
-    book, col, scalar = sds((m, L)), sds((m, 1)), sds((1, 1), jnp.int32)
+    m, levels = spec.num_markets, spec.num_levels
+    book, col, scalar = sds((m, levels)), sds((m, 1)), sds((1, 1), jnp.int32)
     params = MarketParams(*(sds((m, 1), MarketParams.field_dtype(f))
                             for f in MarketParams._fields))
     stats = stats_mod.MarketStats(*(col,) * 6) if stats_only else None
@@ -89,6 +90,69 @@ def test_chunk_kernel_compiles_for_v5e(kernel, stats_only, num_agents,
 
     compiled = jax.jit(run).lower(*args, params=params, stats=stats).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# Tiles at M=2048, L=4096, chunk 64, each at the level tile the policy fits
+# to the VMEM budget: name -> (A, mb, agent chunk). The runner's own tile
+# without a sweep at A=256 ("heuristic"), at A=64 (a one-hot narrower than
+# a lane) and with agent_chunk=64 pinned; with "swept" (the sweep's pick on
+# one v5e) and the rest, every candidate of the sweep at A=256.
+WIDE_TILES = {"heuristic": (256, 8, 128), "heuristic-a64": (64, 8, None),
+              "pinned-ac64": (256, 8, 64), "swept": (256, 16, None),
+              "mb16-ac128": (256, 16, 128), "mb8-all-a": (256, 8, None)}
+#: The runner's options that resolve each of its own tiles.
+RUNNER_TILES = {"heuristic": {}, "heuristic-a64": {},
+                "pinned-ac64": {"agent_chunk": 64}}
+WIDE_CASES = ([(t, False) for t in sorted(WIDE_TILES)]
+              + [("heuristic", True), ("swept", True)])
+
+
+@pytest.mark.parametrize(
+    "tile,stats_only", WIDE_CASES,
+    ids=[f"{t}-{'stats_only' if so else 'paths'}" for t, so in WIDE_CASES])
+def test_wide_grid_chunk_kernel_compiles_for_v5e(tile, stats_only, one_chip,
+                                                 monkeypatch):
+    """The kinetic chunk kernel at L=4096 at the tiles the policy picks:
+    level-tiled binning, 12-stage scans over 4096 lanes, under Mosaic's
+    default scoped VMEM limit."""
+    import jax
+
+    from repro.kernels import autotune as tune
+    from repro.kernels import ops
+
+    num_agents, mb, agent_chunk = WIDE_TILES[tile]
+    spec = _spec(num_agents, num_markets=2048, num_levels=4096)
+    choice = tune.TileChoice(mb=mb, m_padded=2048, agent_chunk=agent_chunk)
+    choice = choice._replace(level_tile=tune.fitting_level_tile(
+        choice, 4096, num_agents, CHUNK))
+    assert choice.level_tile is not None
+    assert tune.fits(choice, 4096, num_agents, CHUNK)
+    if num_agents == A:
+        assert choice in tune.candidate_tiles(2048, A, num_levels=4096,
+                                              chunk=CHUNK)
+    if tile in RUNNER_TILES:
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        runner = ops.open_kinetic_runner(spec, CHUNK, autotune=False,
+                                         **RUNNER_TILES[tile])
+        assert runner.tile == choice
+    args, params, stats = _shapes(spec, one_chip, stats_only)
+
+    def run(*a, params, stats):
+        return kinetic_clearing_chunk(
+            *a, params=params, stats=stats, cfg=spec, chunk=CHUNK, mb=mb,
+            agent_chunk=agent_chunk, level_tile=choice.level_tile,
+            interpret=False, stats_only=stats_only)
+
+    compiled = jax.jit(run).lower(*args, params=params, stats=stats).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_wide_grid_tiles_cover_the_sweep():
+    from repro.kernels import autotune as tune
+
+    cands = tune.candidate_tiles(2048, A, num_levels=4096, chunk=CHUNK)
+    assert {(c.mb, c.agent_chunk) for c in cands} == {
+        (mb, ac) for a, mb, ac in WIDE_TILES.values() if a == A}
 
 
 def test_sharded_ring_runner_compiles_for_v5e_2x2(topo, monkeypatch):
