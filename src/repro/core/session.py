@@ -29,6 +29,19 @@ Design:
   * State buffers are **donated** back to the executable on every chunk
     (``jax.jit(..., donate_argnums=(0,))``); the params operands are *not*
     donated — they persist device-resident across the session's life.
+  * :meth:`Session.stream` keeps **one chunk in flight** on runners whose
+    dispatch is asynchronous (``xp`` is ``jax.numpy``): before it yields
+    chunk k it dispatches chunk k+1, so the caller's host copy of chunk k
+    overlaps chunk k+1 on the device. The ahead dispatch is fed a device
+    copy of the post-k state (books, plus the ``stats_only``
+    accumulators), which it donates; the session keeps the post-k
+    originals — one more state on the device (8.4 MB at M=8192, L=128)
+    besides one more chunk of paths. Between two yields every method sees
+    the state and cursor after the last *yielded* chunk: a call that
+    advances or replaces the state drops the chunk in flight, and the
+    iterator dispatches again from whatever state stands when it resumes.
+    The lookahead shows only in timing and in the ``stream_ahead_total`` /
+    ``stream_ahead_discarded_total`` counters.
   * Chunked execution is bitwise-identical to one-shot: the RNG is a pure
     function of the absolute step coordinate and the scenario overlay keys
     on the absolute step, so chunk boundaries are invisible to the stream.
@@ -69,6 +82,7 @@ import itertools
 from typing import Any, Callable, Dict, Iterator, NamedTuple, Optional, Tuple, Union
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core import params as params_mod
@@ -92,6 +106,10 @@ def _nbytes(tree: Any) -> int:
     """Bytes held by the arrays of a pytree (other leaves count 0)."""
     return sum(int(getattr(x, "nbytes", 0))
                for x in jax.tree_util.tree_leaves(tree))
+
+
+def _copy_leaves(tree: Any) -> Any:
+    return jax.tree_util.tree_map(jnp.copy, tree)
 
 
 class StepBatch(NamedTuple):
@@ -173,11 +191,21 @@ class ChunkRunner:
 
     def __init__(self) -> None:
         self._trace_count = 0
+        self._copy = None
 
     @property
     def trace_count(self) -> int:
         """Times the underlying executable was (re)traced; 0 for host loops."""
         return self._trace_count
+
+    def device_copy(self, tree: Any) -> Callable[[Any], Any]:
+        """The compiled device copy of pytrees shaped and placed like
+        ``tree`` (a fresh buffer per leaf, same sharding): what
+        :meth:`Session.stream` donates to a chunk dispatched ahead. Compiled
+        once per runner, at its first request."""
+        if self._copy is None:
+            self._copy = jax.jit(_copy_leaves).lower(tree).compile()
+        return self._copy
 
     def init_state(self, spec: EnsembleSpec) -> MarketState:
         return initial_state(spec, self.xp)
@@ -512,6 +540,8 @@ class Session:
         self._t = 0
         self._closed = False
         self._active_streams = 0
+        # The (state, aux, batch, stats) of the chunk stream() has in flight.
+        self._ahead: Optional[tuple] = None
         self.metrics = metrics
         if metrics is not None:
             metrics.gauge("chunk", runner.chunk)
@@ -540,6 +570,7 @@ class Session:
 
     def close(self) -> None:
         """Release the device-resident state (the executables stay cached)."""
+        self._drop_ahead()
         self._state = None
         self._params = None
         self._aux = None
@@ -627,13 +658,37 @@ class Session:
         defined — scenario events simply lie behind the cursor). The step
         count (and any horizon error) resolves at the *call*, not lazily at
         first iteration, so the iterator's length is fixed when created.
+
+        On runners whose dispatch is asynchronous (``xp`` is ``jax.numpy``)
+        one chunk is in flight ahead of the consumer: chunk k+1, if it lies
+        inside ``n_steps``, is dispatched before chunk k is yielded, so a
+        caller that copies chunk k to the host does so while chunk k+1 runs.
+        It runs from a device copy of the post-k state (compiled here, at
+        the call), and the session keeps the originals: one more state and
+        one more chunk of paths in device memory. Between two yields the
+        session reads exactly as after the last yielded chunk (``state``,
+        ``step_count``, ``snapshot()``, ``stats``); :meth:`step`,
+        :meth:`run` or :meth:`close` there drops the chunk in flight, and
+        the iterator resumes from the state they leave, on its fixed
+        schedule, as does closing the iterator early. Host-loop runners
+        compute each chunk only when it is asked for.
         """
         self._check_open()
-        return self._stream(self._resolve_steps(n_steps))
+        remaining = self._resolve_steps(n_steps)
+        ahead = self._runner.xp is not np
+        if ahead:
+            # Compile the copy at the call, even for a one-chunk stream that
+            # never dispatches ahead, so that a warm-up's streams compile it.
+            self._runner.device_copy((self._state, self._stats))
+        return self._stream(remaining, ahead)
 
-    def _dispatch(self, runner: ChunkRunner, n: int, ext,
-                  kind: str) -> StepBatch:
-        """One runner dispatch inside its ``kinetic.dispatch`` span.
+    def _launch(self, runner: ChunkRunner, n: int, ext, kind: str,
+                ahead: bool = False):
+        """One runner dispatch from the session's cursor and state inside
+        its ``kinetic.dispatch`` span; returns the runner's ``(state, aux,
+        batch, stats)`` and changes nothing on the session. ``ahead`` feeds
+        the runner a device copy of the state (``kinetic.dispatch.copy``),
+        so the session's own stays valid.
 
         All sampling is strictly outside the jitted call: the span's clock
         reads and two integer trace-counter reads. Nothing here becomes an
@@ -643,29 +698,71 @@ class Session:
         """
         m = self.metrics
         traces0 = runner.trace_count
+        state, stats = self._state, self._stats
         with span("kinetic.dispatch", m, series=f"{kind}_dispatch_seconds",
-                  session=self._sid, step0=self._t, n=n, kind=kind):
-            self._state, self._aux, batch, self._stats = runner.run(
-                self._state, self._params, self._aux, self._t, n, ext,
-                self._stats)
+                  session=self._sid, step0=self._t, n=n, kind=kind,
+                  ahead=int(ahead)):
+            if ahead:
+                with span("kinetic.dispatch.copy",
+                          bytes=_nbytes((state, stats))):
+                    state, stats = runner.device_copy((state, stats))(
+                        (state, stats))
+            out = runner.run(state, self._params, self._aux, self._t, n,
+                             ext, stats)
         if m is not None:
-            m.inc("steps_total", n)
-            if kind == "chunk":
-                m.inc("chunks_total")
             traced = runner.trace_count - traces0
             if traced:
                 m.inc("traces", traced)
+        return out
+
+    def _commit(self, n: int, kind: str, out) -> StepBatch:
+        """Advance the session by a dispatch's ``(state, aux, batch,
+        stats)``."""
+        self._state, self._aux, batch, self._stats = out
+        if self.metrics is not None:
+            self.metrics.inc("steps_total", n)
+            if kind == "chunk":
+                self.metrics.inc("chunks_total")
         self._t += n
         return batch
 
-    def _stream(self, remaining: int) -> Iterator[StepBatch]:
+    def _dispatch(self, runner: ChunkRunner, n: int, ext,
+                  kind: str) -> StepBatch:
+        """Dispatch and advance ``n`` steps, dropping any chunk in flight."""
+        self._drop_ahead()
+        return self._commit(n, kind, self._launch(runner, n, ext, kind))
+
+    def _drop_ahead(self) -> None:
+        if self._ahead is not None:
+            self._ahead = None
+            if self.metrics is not None:
+                self.metrics.inc("stream_ahead_discarded_total")
+
+    def _stream(self, remaining: int,
+                ahead: bool = False) -> Iterator[StepBatch]:
+        chunk = self._runner.chunk
+        pending: Optional[tuple] = None
         self._active_streams += 1
         try:
             while remaining > 0:
-                n = min(self._runner.chunk, remaining)
-                yield self._dispatch(self._runner, n, None, "chunk")
+                n = min(chunk, remaining)
+                if pending is not None and pending is self._ahead:
+                    self._ahead = None
+                    batch = self._commit(n, "chunk", pending)
+                else:
+                    batch = self._dispatch(self._runner, n, None, "chunk")
                 remaining -= n
+                pending = None
+                if ahead and remaining > 0:
+                    pending = self._ahead = self._launch(
+                        self._runner, min(chunk, remaining), None, "chunk",
+                        ahead=True)
+                    if self.metrics is not None:
+                        self.metrics.inc("stream_ahead_total")
+                yield batch
         finally:
+            if pending is not None and pending is self._ahead:
+                self._drop_ahead()
             self._active_streams -= 1
 
     def run(self, n_steps: Optional[int] = None) -> StepBatch:

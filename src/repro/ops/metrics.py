@@ -32,8 +32,13 @@ duration. The program's spans (``kbench/spans.py`` reduces them):
     ``kinetic.step.orders``      validation and lowering of the orders to
                                  dense [M, L] (``bytes``)
   ``kinetic.dispatch``           one runner call (``session``, ``step0``,
-                                 ``n``, ``kind`` = chunk/step); nests in
+                                 ``n``, ``kind`` = chunk/step, ``ahead`` =
+                                 1 for a chunk ``Session.stream``
+                                 dispatched before the caller consumed the
+                                 previous one, else 0); nests in
                                  ``kinetic.step`` for a step
+    ``kinetic.dispatch.copy``    an ahead chunk: the device copy of the
+                                 state it is fed (``bytes``; enqueue)
     ``kinetic.dispatch.operands``  Pallas runners: the step0/n_valid
                                  scalars and the external orders (``bytes``)
     ``kinetic.dispatch.launch``  the jitted chunk call (enqueue)
@@ -54,9 +59,14 @@ duration. The program's spans (``kbench/spans.py`` reduces them):
 ``Engine.open`` creates (disable with ``Engine(backend, metrics=False)`` or
 per-session ``open(spec, metrics=False)``). The session records:
 
-  counters  ``steps_total``, ``chunks_total``, ``traces`` (retrace counter:
-            0 on a warm engine; two integer reads per dispatch),
-            ``snapshots_total``, ``restores_total``, ``swaps_total``
+  counters  ``steps_total``, ``chunks_total`` (advanced: a chunk counts
+            once it is yielded), ``traces`` (retrace counter: 0 on a warm
+            engine; two integer reads per dispatch), ``snapshots_total``,
+            ``restores_total``, ``swaps_total``,
+            ``stream_ahead_total`` (chunks ``Session.stream`` dispatched
+            while the previous batch was not yet consumed),
+            ``stream_ahead_discarded_total`` (of those, dropped unyielded
+            by an early close or a mid-stream ``step``/``run``/``close``)
   timings   (count/total/min/max aggregates, timed by their spans)
             ``chunk_dispatch_seconds``, ``step_dispatch_seconds`` — the
             host time to *enqueue* a chunk or step (``kinetic.dispatch``;

@@ -24,6 +24,10 @@ carried *state* buffers are donated).
 
 On host-loop backends (numpy) conversion is free and the pipeline
 degenerates to a one-item delay line — same semantics, no overlap to win.
+
+``Session.stream`` now overlaps for its own streaming callers (it
+dispatches chunk k+1 before yielding chunk k); the gateway advances through
+``Session.run``, which does not dispatch ahead, and keeps this lag-one.
 """
 from __future__ import annotations
 
