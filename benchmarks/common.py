@@ -24,7 +24,6 @@ AGENT_SWEEP = [16, 64, 256, 1024] if FULL else [16, 64, 256]
 FIXED_M = 8192 if FULL else 128
 FIXED_A = 256 if FULL else 128
 STEPS = 500 if FULL else 50
-LEVELS = 128
 
 Row = Tuple[str, float, str]
 

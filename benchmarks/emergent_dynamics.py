@@ -42,7 +42,7 @@ def high_vol_smoke_config(num_steps: int = 500) -> MarketConfig:
 
 
 def _sweep_config(amom: float) -> MarketConfig:
-    # Calibrated dynamics parameterization (EXPERIMENTS.md §Fig7: the
+    # Calibrated dynamics parameterization (PERF.md, section 8: the
     # paper omits noise_delta / P_mkt; these values reproduce all four
     # stylized facts qualitatively).
     return MarketConfig(num_markets=M, num_agents=256, num_steps=S,

@@ -92,9 +92,8 @@ class MarketParams(NamedTuple):
         return np.int32 if field in _INT_FIELDS else np.float32
 
     def asarray(self, xp) -> "MarketParams":
-        """Dtype-preserving placement into array module ``xp`` — the single
-        live copy of the per-field dtype coercion, shared by the session
-        placement hook, the kernels' spec fallback, and the autotuner."""
+        """Dtype-preserving placement into array module ``xp`` — the
+        session's placement hook for every backend's params."""
         return MarketParams(*(
             xp.asarray(np.asarray(leaf), dtype=MarketParams.field_dtype(f))
             for f, leaf in zip(MarketParams._fields, self)))
@@ -222,10 +221,9 @@ def params_from_config(cfg: MarketConfig, num_markets: int = None,
 
 
 def scalar_params(cfg: MarketConfig, xp) -> MarketParams:
-    """Broadcastable ``[1, 1]`` constant params for legacy scalar-config
-    entry points (the one-shot kernels, the jitted reference oracle): inside
-    a trace these fold to the exact constants the pre-ensemble code used, so
-    the scalar path stays bitwise-identical to the seed engine."""
+    """Broadcastable ``[1, 1]`` constant params of one scalar config, as
+    the jitted reference oracle (:mod:`repro.kernels.ref`) passes them:
+    built with ``xp``, they fold to constants inside its trace."""
     return params_from_config(cfg, num_markets=1, xp=xp)
 
 

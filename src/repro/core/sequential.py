@@ -28,12 +28,9 @@ reports.
 """
 from __future__ import annotations
 
-from typing import Optional
-
 import numpy as np
 
 from repro.core import agents, auction
-from repro.core import params as params_mod
 from repro.core.params import MarketParams
 from repro.core.step import (
     MarketState,
@@ -99,8 +96,9 @@ def simulate_step_sequential(
     step_idx,
     market_ids,
     xp,
+    *,
+    params: MarketParams,
     uniform_fn=None,
-    params: Optional[MarketParams] = None,
     atype=None,
     seed=None,
     peer_mid=None,
@@ -116,8 +114,6 @@ def simulate_step_sequential(
     the auction's ``p_star``), falling back to the previous last price on
     no-trade steps.
     """
-    if params is None:
-        params = params_mod.scalar_params(cfg, xp)
     f32 = xp.float32
     A = cfg.num_agents
 

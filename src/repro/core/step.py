@@ -11,21 +11,17 @@ Scenario effects are selected by per-market :class:`repro.core.params
 traced step index, so *every* scenario — and every per-market mixture of
 scenarios — compiles to the same fused kernel as the baseline: no
 data-dependent control flow ever reaches the Pallas grid, and no scenario
-value is baked into a trace. Legacy scalar-config callers (the one-shot
-kernels, the jitted oracle) omit ``params``; the constants are then derived
-from ``cfg`` inside the trace, bitwise-identical to the pre-ensemble code
-on every counter-RNG backend (the stateful ``numpy-pcg64`` reference —
-statistical-equivalence only — shifted by the fixed five-channel draw
-schedule; see :mod:`repro.core.agents`).
+value is baked into a trace. Every caller passes ``params``; the jitted
+oracle (:mod:`repro.kernels.ref`) passes a scalar config's broadcastable
+constants (:func:`repro.core.params.scalar_params`).
 """
 from __future__ import annotations
 
-from typing import Callable, NamedTuple, Optional
+from typing import Callable, NamedTuple
 
 import numpy as np
 
 from repro.core import agents, auction
-from repro.core import params as params_mod
 from repro.core.params import MarketParams
 
 
@@ -207,6 +203,8 @@ def simulate_step(
     step_idx,
     market_ids,
     xp,
+    *,
+    params: MarketParams,
     bin_orders: Callable = None,
     scan: str = "cumsum",
     uniform_fn: Callable = None,
@@ -214,7 +212,6 @@ def simulate_step(
     ext_ask=None,
     agent_chunk=None,
     level_tile=None,
-    params: Optional[MarketParams] = None,
     atype=None,
     seed=None,
     peer_mid=None,
@@ -224,9 +221,8 @@ def simulate_step(
     ``cfg`` supplies only the static trace parameters (``num_agents``,
     ``num_levels``, ``seed``) — a ``MarketConfig`` or an ``EnsembleSpec``.
     ``params`` carries every scenario-varying value as per-market ``[M, 1]``
-    runtime operands; when omitted (legacy scalar-config callers) it is
-    derived from ``cfg`` as broadcastable ``[1, 1]`` constants inside the
-    trace, which folds to exactly the pre-ensemble computation.
+    runtime operands (or broadcastable ``[1, 1]`` constants: see
+    :func:`repro.core.params.scalar_params`).
 
     ``ext_buy``/``ext_ask`` (optional float32[M, L]) are externally injected
     order quantities — the session layer's reserved agent slot for RL-style
@@ -250,15 +246,8 @@ def simulate_step(
     :func:`resolve_peer_mids`) so every backend freezes coupling at the
     same boundaries. ``None`` falls back to ``state.prev_mid``
     (self-coupling, per step) — value-identical whenever no arbitrageurs
-    are populated, which is every legacy call site.
+    are populated.
     """
-    if params is None:
-        # Built with xp, not host numpy: Pallas kernel bodies reject
-        # captured host-array constants, so the legacy traced entries embed
-        # xp constants (and keep the dead shock selects for XLA to chew
-        # on). The concrete-zero elisions fire where they pay — the NumPy
-        # host-loop backends, whose session params are host arrays.
-        params = params_mod.scalar_params(cfg, xp)
     if bin_orders is None:
         bin_orders = lambda s, p, q: bin_orders_onehot(
             s, p, q, cfg.num_levels, xp, agent_chunk=agent_chunk,

@@ -1,8 +1,8 @@
-"""Tile selection for the persistent clearing kernels: pad, don't degrade.
+"""Tile selection for the clearing kernels: pad, don't degrade.
 
-The seed's ``pick_tile`` required ``mb`` to *divide* M, so a prime or odd
-ensemble size degraded to MB=1 — one market per grid cell, an 8× sublane
-under-utilization on TPU. This module replaces that policy:
+A tile whose MB had to *divide* M would leave a prime or odd ensemble size
+at MB=1 — one market per grid cell, an 8× sublane under-utilization on
+TPU. So the market axis is padded instead:
 
   * :func:`auto_tile` always returns a sublane-aligned tile (MB a multiple
     of 8) and the padded ensemble size ``m_padded`` that makes the grid

@@ -5,29 +5,33 @@ memory for all S steps, atomicAdd order binning, Hillis–Steele scans,
 tournament argmax.
 
 TPU adaptation (PERF.md, section 3): one Pallas grid cell per *tile* of MB
-markets.
-The entire S-step loop runs inside the kernel body; the books live in VMEM
-(registers/VMEM values carried through ``lax.fori_loop``) and touch HBM only
-at kernel entry/exit — HBM traffic is Θ(M·L), independent of S, exactly the
-paper's claim. Order binning is a one-hot MXU contraction (the TPU-native
-replacement for shared-memory atomics); clearing runs the same xp-polymorphic
-``auction.clear`` / ``agents.decide`` code as every other backend, so results
-are bitwise identical.
+markets. A chunk's whole step loop runs inside the kernel body; the books
+live in VMEM (values carried through ``lax.fori_loop``) and touch HBM only
+at kernel entry/exit — HBM traffic is Θ(M·L), independent of the chunk
+length, exactly the paper's claim. Order binning is a one-hot MXU
+contraction (the TPU-native replacement for shared-memory atomics);
+clearing runs the same xp-polymorphic ``auction.clear`` / ``agents.decide``
+code as every other backend, so results are bitwise identical.
 
-Block/tile layout: markets on sublanes (MB a multiple of 8 — the chunk
-entries *pad* the market axis to a tile multiple instead of shrinking MB, so
-prime/odd M keeps full sublane tiles; see :mod:`repro.kernels.autotune`),
-price ticks on lanes (L multiple of 128 native; smaller L still correct,
-just padded by the compiler; up to ``MAX_LEVELS`` = 4096, where the
-Hillis–Steele scans take 12 stages of whole-vreg lane shifts). VMEM
-working set per grid cell ≈ ``K·MB·L`` for the step's level-wide values
-(K ≈ 40, fitted to Mosaic's allocation at L=4096) ``+ MB·Lt·Ac`` (the
-level-major [MB, Lt, Ac] one-hot binning, Lt = level_tile ≤ L, Ac =
-agent_chunk ≤ A, padded to whole lanes; an agent chunk narrower than a
-lane adds ``6·MB·MB·L``) ``+ 3·MB·chunk`` f32 for path
-outputs, plus a negligible ``22·MB`` term for the per-market parameter
-columns (the :class:`repro.core.params.MarketParams` operands, one
-``(MB, 1)`` block each) — padding adds only whole-tile rows, so the
+One entry per kernel family: :func:`kinetic_clearing_chunk` here and
+:func:`repro.kernels.naive_clearing.naive_clearing_chunk` (the launch-per-
+step ablation) are the only ways into the Pallas kernels. Both take the
+same operands, prepared once by :func:`_chunk_operands`, and both are
+reached through :class:`repro.kernels.ops.PallasChunkRunner`.
+
+Block/tile layout: markets on sublanes (MB a multiple of 8: the entries pad
+the market axis to a tile multiple with zero rows, so prime/odd M keeps
+full sublane tiles; see :mod:`repro.kernels.autotune`), price ticks on
+lanes (L multiple of 128 native; smaller L still correct, just padded by
+the compiler; up to ``MAX_LEVELS`` = 4096, where the Hillis–Steele scans
+take 12 stages of whole-vreg lane shifts). VMEM working set per grid cell
+≈ ``K·MB·L`` for the step's level-wide values (K ≈ 40, fitted to Mosaic's
+allocation at L=4096) ``+ MB·Lt·Ac`` (the level-major [MB, Lt, Ac] one-hot
+binning, Lt = level_tile ≤ L, Ac = agent_chunk ≤ A, padded to whole lanes;
+an agent chunk narrower than a lane adds ``6·MB·MB·L``) ``+ 3·MB·chunk``
+f32 for path outputs, plus a negligible ``22·MB`` term for the per-market
+parameter columns (the :class:`repro.core.params.MarketParams` operands,
+one ``(MB, 1)`` block each) — padding adds only whole-tile rows, so the
 padded-tile term is the same ``MB·(...)`` budget with ``grid =
 ceil(M/MB)`` cells. :func:`repro.kernels.autotune.estimate_vmem_bytes`
 is this formula; the tile policy keeps every tile under Mosaic's default
@@ -38,12 +42,11 @@ HBM output traffic independent of the chunk length — see PERF.md
 (sections 5 and 6) for the measured budget.
 
 Scenario engine: archetype mixtures and scenario overlays (flash-crash
-shock, volatility regimes, book seeding) are static ``cfg`` fields dispatched
-branch-free inside ``simulate_step`` — every scenario traces to the same
-fully fused persistent kernel, and baseline configs trace the identical
-graph as before the scenario engine existed.
+shock, volatility regimes, book seeding) are dispatched branch-free inside
+``simulate_step``, so every scenario traces to the same fully fused
+persistent kernel.
 
-Sharding: the chunk entry takes an explicit per-row ``market_ids`` operand
+Sharding: the entry takes an explicit per-row ``market_ids`` operand
 (instead of deriving ids from the grid index), so a ``shard_map`` caller can
 hand each device its true *global* market coordinates — the RNG stream is a
 pure function of (seed, market id, step), which is what makes a sharded run
@@ -51,7 +54,7 @@ bitwise-identical to the single-device run. See ``repro.kernels.ops``.
 
 Heterogeneous ensembles: every scenario-varying parameter — shock schedule
 and intensities, marketable-flow probability, quantity cap, archetype
-knobs, per-market population counts — enters the chunk entry as a
+knobs, per-market population counts — enters the entry as a required
 :class:`repro.core.params.MarketParams` operand of ``[M, 1]`` columns.
 Each grid cell fetches its tile's rows (``(mb, 1)`` blocks on the sublane
 axis, exactly like the ``market_ids``/``last_price`` scalars), so a single
@@ -74,8 +77,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import params as params_mod
 from repro.core import stats as stats_mod
-from repro.core.config import MarketConfig
-from repro.core.params import EnsembleSpec, MarketParams
+from repro.core.params import MarketParams
 from repro.core.step import MarketState, resolve_peer_mids, simulate_step
 from repro.kernels.autotune import pad_to_multiple
 
@@ -117,23 +119,6 @@ def write_column(path, s, col):
     return jnp.where(cols == s, col, path)
 
 
-def tile_market_ids(mb: int):
-    """Global market ids of grid cell ``program_id(0)``'s rows, [mb, 1]."""
-    rows = jax.lax.broadcasted_iota(jnp.int32, (mb, 1), 0)
-    return pl.program_id(0) * mb + rows
-
-
-def resolve_params(cfg, M: int, params: Optional[MarketParams],
-                   xp) -> MarketParams:
-    """The chunk entries' params operand: explicit > spec-owned > scalar
-    broadcast of a legacy ``MarketConfig`` (value-identical constants)."""
-    if params is not None:
-        return params
-    if isinstance(cfg, EnsembleSpec):
-        return cfg.params.asarray(xp)
-    return params_mod.params_from_config(cfg, M, xp)
-
-
 def pad_params(params: MarketParams, m_padded: int) -> MarketParams:
     """Dtype-preserving zero-row padding of every parameter column (a pad
     row is a zero-count, zero-intensity market whose outputs are sliced
@@ -142,64 +127,6 @@ def pad_params(params: MarketParams, m_padded: int) -> MarketParams:
         _pad_rows(jnp.asarray(leaf, dtype=MarketParams.field_dtype(f)),
                   m_padded)
         for f, leaf in zip(MarketParams._fields, params)))
-
-
-def _kernel_body(
-    bid_ref, ask_ref, last_ref, pmid_ref,
-    out_bid_ref, out_ask_ref, out_last_ref, out_pmid_ref,
-    price_path_ref, volume_path_ref,
-    *, cfg: MarketConfig, mb: int,
-):
-    """Persistent scheduler (paper Alg. 1) for one tile of ``mb`` markets."""
-    S = cfg.num_steps
-
-    # Phase 1: load opening books into VMEM-resident values (Alg.1 lines 2-3).
-    bid = bid_ref[...]
-    ask = ask_ref[...]
-    last = last_ref[...]
-    pmid = pmid_ref[...]
-
-    market_ids = tile_market_ids(mb)
-
-    def body(s, carry):
-        bid, ask, last, pmid, pp, vp = carry
-        state = MarketState(bid=bid, ask=ask, last_price=last, prev_mid=pmid)
-        # Phases 2-5 (Alg.1 lines 5-22): shared semantics, one-hot binning.
-        new_state, out = simulate_step(
-            cfg, state, s, market_ids, jnp, bin_orders=None, scan=KERNEL_SCAN
-        )
-        pp = write_column(pp, s, out.price)
-        vp = write_column(vp, s, out.volume)
-        return (new_state.bid, new_state.ask, new_state.last_price,
-                new_state.prev_mid, pp, vp)
-
-    pp0 = jnp.zeros((mb, S), jnp.float32)
-    vp0 = jnp.zeros((mb, S), jnp.float32)
-    bid, ask, last, pmid, pp, vp = jax.lax.fori_loop(
-        0, S, body, (bid, ask, last, pmid, pp0, vp0)
-    )
-
-    # Final writeback (Alg.1 line 24) — the only per-market HBM stores.
-    out_bid_ref[...] = bid
-    out_ask_ref[...] = ask
-    out_last_ref[...] = last
-    out_pmid_ref[...] = pmid
-    price_path_ref[...] = pp
-    volume_path_ref[...] = vp
-
-
-def pick_tile(num_markets: int, target: int = 8) -> int:
-    """Largest divisor of M that is <= target (sublane-aligned when possible).
-
-    Legacy policy for the exact-grid one-shot entries (`kinetic_clearing`,
-    `naive_clearing`): prime/odd M degrades to MB=1. The session chunk
-    entries instead pad the market axis and keep full sublane tiles — see
-    :func:`repro.kernels.autotune.auto_tile`.
-    """
-    mb = min(target, num_markets)
-    while num_markets % mb:
-        mb -= 1
-    return mb
 
 
 def _pad_rows(x, m_padded: int):
@@ -221,8 +148,9 @@ def _chunk_kernel_body(
     cfg, mb: int, chunk: int,
     agent_chunk: Optional[int], level_tile: Optional[int], stats_only: bool,
 ):
-    """Session variant of the persistent scheduler: a fixed ``chunk``-length
-    trace that serves *any* requested step count and *any* scenario mixture.
+    """Persistent scheduler (paper Alg. 1) for one tile of ``mb`` markets:
+    a fixed ``chunk``-length trace that serves *any* requested step count
+    and *any* scenario mixture.
 
     ``step0`` (runtime scalar) offsets the RNG / scenario step coordinate so
     a warm session resumes mid-stream; ``n_valid`` (runtime scalar) gates the
@@ -338,32 +266,70 @@ def _chunk_kernel_body(
     out_pmid_ref[...] = pmid
 
 
+def _chunk_operands(bid, ask, last, pmid, ext_buy, ext_ask, *, mb: int,
+                    params: MarketParams, market_ids, peer_mid, stats,
+                    stats_only: bool):
+    """The operand prologue both chunk entries share: every per-market
+    operand on the market axis padded to a multiple of ``mb``.
+
+    Returns ``(m_padded, rows, params, stats)``. ``rows`` are the
+    ``[m_padded, ·]`` operands ``(market_ids, bid, ask, last, pmid,
+    ext_buy, ext_ask, peer_mid)`` in the kernels' operand order; ``params``
+    and, in ``stats_only`` mode, ``stats`` are padded alike (``stats`` is
+    ``None`` otherwise). Pad rows are zero books with zero parameters (a
+    zero-count, zero-intensity market) and distinct ids ``>= M``; every
+    output row past ``M`` is sliced off by the entry.
+    """
+    if stats_only and stats is None:
+        raise ValueError("stats_only=True requires the carried `stats` "
+                         "accumulators (see repro.core.stats.init_stats)")
+    M = bid.shape[0]
+    m_padded = pad_to_multiple(M, mb)
+    if market_ids is None:
+        market_ids = jnp.arange(M, dtype=jnp.int32)
+    mids = jnp.reshape(jnp.asarray(market_ids, dtype=jnp.int32), (M, 1))
+    if m_padded != M:
+        pad_ids = jnp.arange(M, m_padded, dtype=jnp.int32)[:, None]
+        mids = jnp.concatenate([mids, pad_ids], axis=0)
+    if peer_mid is None:
+        # Single-device default: gather the chunk-entry mids at the peer
+        # rows (local indices == global ids here).
+        peer_mid = resolve_peer_mids(pmid, params.coupling_peer, jnp)
+    rows = (mids,) + tuple(
+        _pad_rows(x, m_padded)
+        for x in (bid, ask, last, pmid, ext_buy, ext_ask, peer_mid))
+    params = pad_params(params, m_padded)
+    if stats_only:
+        stats = stats_mod.MarketStats(
+            *(_pad_rows(jnp.asarray(x, dtype=jnp.float32), m_padded)
+              for x in stats))
+    return m_padded, rows, params, stats if stats_only else None
+
+
 def kinetic_clearing_chunk(
     bid: jax.Array, ask: jax.Array, last: jax.Array, pmid: jax.Array,
     step0: jax.Array, n_valid: jax.Array,
     ext_buy: jax.Array, ext_ask: jax.Array,
-    *, cfg, chunk: int, mb: int = 8,
+    *, cfg, chunk: int, params: MarketParams, mb: int = 8,
     interpret: bool = False, market_ids: Optional[jax.Array] = None,
     agent_chunk: Optional[int] = None, level_tile: Optional[int] = None,
-    params: Optional[MarketParams] = None,
     peer_mid: Optional[jax.Array] = None,
     stats: Optional[stats_mod.MarketStats] = None, stats_only: bool = False,
 ) -> Tuple[jax.Array, ...]:
-    """``num_steps``-parametrized persistent entry for the Session API.
+    """The persistent kernel's entry: up to ``chunk`` steps in one launch.
 
     One trace (per static ``chunk`` length) serves every chunk of up to
     ``chunk`` steps: ``step0``/``n_valid`` are int32[1, 1] runtime scalars,
     and every scenario-varying parameter is a per-market ``[M, 1]`` operand
-    (``params``, a :class:`repro.core.params.MarketParams`; defaults to the
-    spec's own params, or to a broadcast of a legacy scalar config — the
-    scalar default is value-identical to the pre-ensemble constants).
+    (``params``, a :class:`repro.core.params.MarketParams`). ``cfg`` (an
+    ``EnsembleSpec`` or ``MarketConfig``) supplies only the static trace
+    values ``num_agents``, ``num_levels`` and ``seed``.
     Deliberately *not* jitted here — the session runner owns the ``jax.jit``
     wrapper so it can donate the state buffers and count traces.
 
     The market axis is padded to a multiple of ``mb`` with benign zero rows
     (and sliced back), so any M — prime, odd, tiny — runs full sublane-
-    aligned tiles; parameter columns pad with zero rows too (a zero-count,
-    shock-at-0-with-zero-intensity market whose outputs are discarded).
+    aligned tiles (:func:`_chunk_operands`).
     ``market_ids`` (optional int32[M] / [M, 1]) carries each row's global
     coordinate for sharded callers; it defaults to ``arange(M)``.
 
@@ -386,24 +352,11 @@ def kinetic_clearing_chunk(
     meaningful otherwise.
     """
     M, L = bid.shape
-    m_padded = pad_to_multiple(M, mb)
+    m_padded, rows, params, stats = _chunk_operands(
+        bid, ask, last, pmid, ext_buy, ext_ask, mb=mb, params=params,
+        market_ids=market_ids, peer_mid=peer_mid, stats=stats,
+        stats_only=stats_only)
     grid = (m_padded // mb,)
-
-    if market_ids is None:
-        market_ids = jnp.arange(M, dtype=jnp.int32)
-    mids = jnp.reshape(jnp.asarray(market_ids, dtype=jnp.int32), (M, 1))
-    if m_padded != M:
-        pad_ids = jnp.arange(M, m_padded, dtype=jnp.int32)[:, None]
-        mids = jnp.concatenate([mids, pad_ids], axis=0)
-    params = resolve_params(cfg, M, params, jnp)
-    if peer_mid is None:
-        # Single-device default: gather the chunk-entry mids at the peer
-        # rows (local indices == global ids here).
-        peer_mid = resolve_peer_mids(pmid, params.coupling_peer, jnp)
-    bid, ask, last, pmid, ext_buy, ext_ask, peer_mid = (
-        _pad_rows(x, m_padded) for x in (bid, ask, last, pmid, ext_buy,
-                                         ext_ask, peer_mid))
-    params = pad_params(params, m_padded)
 
     book_spec = pl.BlockSpec((mb, L), lambda i: (i, 0))
     scalar_spec = pl.BlockSpec((mb, 1), lambda i: (i, 0))
@@ -419,16 +372,9 @@ def kinetic_clearing_chunk(
     in_specs = [step_spec, step_spec, scalar_spec, book_spec, book_spec,
                 scalar_spec, scalar_spec, book_spec, book_spec,
                 scalar_spec] + [scalar_spec] * NUM_PARAM_OPERANDS
-    operands = [step0, n_valid, mids, bid, ask, last, pmid, ext_buy,
-                ext_ask, peer_mid] + list(params)
+    operands = [step0, n_valid, *rows, *params]
 
     if stats_only:
-        if stats is None:
-            raise ValueError("stats_only=True requires the carried `stats` "
-                             "accumulators (see repro.core.stats.init_stats)")
-        stats = stats_mod.MarketStats(
-            *(_pad_rows(jnp.asarray(x, dtype=jnp.float32), m_padded)
-              for x in stats))
         stats_shape = jax.ShapeDtypeStruct((m_padded, 1), jnp.float32)
         in_specs += [scalar_spec] * 6
         operands += list(stats)
@@ -458,45 +404,3 @@ def kinetic_clearing_chunk(
     if stats_only:
         return out[:4] + (stats_mod.MarketStats(*out[4:]),)
     return out
-
-
-@functools.partial(jax.jit, static_argnames=("cfg", "mb", "interpret"))
-def kinetic_clearing(
-    bid: jax.Array, ask: jax.Array, last: jax.Array, pmid: jax.Array,
-    *, cfg: MarketConfig, mb: int = 8, interpret: bool = False,
-) -> Tuple[jax.Array, ...]:
-    """Run the full S-step ensemble simulation in one persistent kernel.
-
-    Args:
-      bid/ask: float32[M, L] opening books; last/pmid: float32[M, 1].
-    Returns:
-      (bid, ask, last, pmid, price_path[M, S], volume_path[M, S]).
-    """
-    M, L = bid.shape
-    S = cfg.num_steps
-    if M % mb:
-        raise ValueError(f"M={M} not divisible by tile mb={mb}")
-    grid = (M // mb,)
-
-    book_spec = pl.BlockSpec((mb, L), lambda i: (i, 0))
-    scalar_spec = pl.BlockSpec((mb, 1), lambda i: (i, 0))
-    path_spec = pl.BlockSpec((mb, S), lambda i: (i, 0))
-
-    out_shapes = (
-        jax.ShapeDtypeStruct((M, L), jnp.float32),
-        jax.ShapeDtypeStruct((M, L), jnp.float32),
-        jax.ShapeDtypeStruct((M, 1), jnp.float32),
-        jax.ShapeDtypeStruct((M, 1), jnp.float32),
-        jax.ShapeDtypeStruct((M, S), jnp.float32),
-        jax.ShapeDtypeStruct((M, S), jnp.float32),
-    )
-    return pallas_call(
-        functools.partial(_kernel_body, cfg=cfg, mb=mb),
-        name="kinetic_clearing_chunk",
-        grid=grid,
-        in_specs=[book_spec, book_spec, scalar_spec, scalar_spec],
-        out_specs=(book_spec, book_spec, scalar_spec, scalar_spec,
-                   path_spec, path_spec),
-        out_shape=out_shapes,
-        interpret=interpret,
-    )(bid, ask, last, pmid)

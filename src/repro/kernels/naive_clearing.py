@@ -1,7 +1,7 @@
 """Naive per-step kernel — the paper's "Naive Custom CUDA" ablation on TPU.
 
 Identical device-side semantics (same ``agents.decide``, same
-``auction.clear``, same RNG), but the two central optimizations removed:
+``auction.clear``, same RNG), but the central optimization removed:
 
   * **No persistence**: one ``pallas_call`` per simulation step, driven by a
     host-level ``lax.scan``. The book round-trips HBM every step — the
@@ -19,13 +19,14 @@ branch-free inside the shared ``simulate_step``, so this ablation stays
 bitwise comparable to the persistent kernel on every scenario — the basis of
 the parity matrix in tests/test_parity_matrix.py.
 
-The chunk entry mirrors :func:`kinetic_clearing_chunk`'s full contract —
-padded sublane tiles, explicit global ``market_ids`` for sharded callers,
-per-market :class:`repro.core.params.MarketParams` operands (``(mb, 1)``
-columns fetched into each tile, so one compiled step kernel serves any
-scenario mixture), and a ``stats_only`` mode (accumulated in the host scan
-carry here, since per-step launches are this ablation's point) — so the
-Session/shard layers treat both engines uniformly.
+Its one entry, :func:`naive_clearing_chunk`, takes the persistent entry's
+operands, prepared by the same prologue
+(:func:`repro.kernels.kinetic_clearing._chunk_operands`: padded sublane
+tiles, explicit global ``market_ids``, per-market
+:class:`repro.core.params.MarketParams` columns, the frozen peer mids), so
+the Session/shard layers treat both engines uniformly. Its ``stats_only``
+mode accumulates in the host scan's carry, since per-step launches are this
+ablation's point.
 """
 from __future__ import annotations
 
@@ -37,87 +38,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core import stats as stats_mod
-from repro.core.config import MarketConfig
 from repro.core.params import MarketParams
-from repro.core.step import MarketState, resolve_peer_mids, simulate_step
-from repro.kernels.autotune import pad_to_multiple
+from repro.core.step import MarketState, simulate_step
 from repro.kernels.kinetic_clearing import (KERNEL_SCAN, NUM_PARAM_OPERANDS,
-                                            _pad_rows, pad_params,
-                                            pallas_call, resolve_params,
-                                            tile_market_ids)
-
-
-def _step_kernel_body(
-    step_ref,
-    bid_ref, ask_ref, last_ref, pmid_ref,
-    out_bid_ref, out_ask_ref, out_last_ref, out_pmid_ref,
-    price_ref, volume_ref,
-    *, cfg: MarketConfig, mb: int,
-):
-    s = step_ref[0, 0]
-    market_ids = tile_market_ids(mb)
-    state = MarketState(
-        bid=bid_ref[...], ask=ask_ref[...],
-        last_price=last_ref[...], prev_mid=pmid_ref[...],
-    )
-    new_state, out = simulate_step(cfg, state, s, market_ids, jnp,
-                                   scan=KERNEL_SCAN)
-    out_bid_ref[...] = new_state.bid
-    out_ask_ref[...] = new_state.ask
-    out_last_ref[...] = new_state.last_price
-    out_pmid_ref[...] = new_state.prev_mid
-    price_ref[...] = out.price
-    volume_ref[...] = out.volume
-
-
-@functools.partial(jax.jit, static_argnames=("cfg", "mb", "interpret"))
-def naive_clearing(
-    bid: jax.Array, ask: jax.Array, last: jax.Array, pmid: jax.Array,
-    *, cfg: MarketConfig, mb: int = 8, interpret: bool = False,
-) -> Tuple[jax.Array, ...]:
-    """S launches of a single-step kernel; state resides in HBM between steps."""
-    M, L = bid.shape
-    S = cfg.num_steps
-    if M % mb:
-        raise ValueError(f"M={M} not divisible by tile mb={mb}")
-    grid = (M // mb,)
-
-    book_spec = pl.BlockSpec((mb, L), lambda i: (i, 0))
-    scalar_spec = pl.BlockSpec((mb, 1), lambda i: (i, 0))
-    step_spec = pl.BlockSpec((1, 1), lambda i: (0, 0))
-
-    out_shapes = (
-        jax.ShapeDtypeStruct((M, L), jnp.float32),
-        jax.ShapeDtypeStruct((M, L), jnp.float32),
-        jax.ShapeDtypeStruct((M, 1), jnp.float32),
-        jax.ShapeDtypeStruct((M, 1), jnp.float32),
-        jax.ShapeDtypeStruct((M, 1), jnp.float32),
-        jax.ShapeDtypeStruct((M, 1), jnp.float32),
-    )
-    step_call = pallas_call(
-        functools.partial(_step_kernel_body, cfg=cfg, mb=mb),
-        name="naive_clearing_step",
-        grid=grid,
-        in_specs=[step_spec, book_spec, book_spec, scalar_spec, scalar_spec],
-        out_specs=(book_spec, book_spec, scalar_spec, scalar_spec,
-                   scalar_spec, scalar_spec),
-        out_shape=out_shapes,
-        interpret=interpret,
-    )
-
-    def host_step(carry, s):
-        bid, ask, last, pmid = carry
-        step_arr = jnp.full((1, 1), s, dtype=jnp.int32)
-        bid, ask, last, pmid, price, volume = step_call(
-            step_arr, bid, ask, last, pmid
-        )
-        return (bid, ask, last, pmid), (price[:, 0], volume[:, 0])
-
-    steps = jnp.arange(S, dtype=jnp.int32)
-    (bid, ask, last, pmid), (pp, vp) = jax.lax.scan(
-        host_step, (bid, ask, last, pmid), steps
-    )
-    return bid, ask, last, pmid, pp.T, vp.T
+                                            _chunk_operands, pallas_call)
 
 
 def _chunk_step_kernel_body(
@@ -127,7 +51,7 @@ def _chunk_step_kernel_body(
     *refs,
     cfg, mb: int, agent_chunk: Optional[int], level_tile: Optional[int],
 ):
-    """Per-step kernel with external-order inputs (Session API variant).
+    """One step for one tile of ``mb`` markets, with external-order inputs.
 
     ``mids_ref`` carries the per-row global market ids (see the kinetic
     chunk kernel) so padded/sharded callers keep exact RNG coordinates;
@@ -164,44 +88,31 @@ def naive_clearing_chunk(
     bid: jax.Array, ask: jax.Array, last: jax.Array, pmid: jax.Array,
     step0: jax.Array, n_valid: jax.Array,
     ext_buy: jax.Array, ext_ask: jax.Array,
-    *, cfg, chunk: int, mb: int = 8,
+    *, cfg, chunk: int, params: MarketParams, mb: int = 8,
     interpret: bool = False, market_ids: Optional[jax.Array] = None,
     agent_chunk: Optional[int] = None, level_tile: Optional[int] = None,
-    params: Optional[MarketParams] = None,
     peer_mid: Optional[jax.Array] = None,
     stats: Optional[stats_mod.MarketStats] = None, stats_only: bool = False,
 ) -> Tuple[jax.Array, ...]:
     """Session entry for the launch-per-step regime: ``chunk`` kernel
     launches per call, state round-tripping HBM between launches.
 
-    Mirrors :func:`kinetic_clearing_chunk`'s contract — ``step0``/``n_valid``
-    int32[1, 1] runtime scalars, per-market ``params`` operands (one trace
-    serves any scenario mixture), external orders injected at the first
-    local step, gated state so a partial tail advances exactly ``n_valid``
-    steps, padded sublane tiles with explicit global ``market_ids``, and a
+    Takes :func:`kinetic_clearing_chunk`'s arguments and returns its
+    outputs — ``step0``/``n_valid`` int32[1, 1] runtime scalars, the
+    required per-market ``params`` operands (one trace serves any scenario
+    mixture), external orders injected at the first local step, gated state
+    so a partial tail advances exactly ``n_valid`` steps, and a
     ``stats_only`` mode (accumulated in the scan carry between launches) —
     but keeps the Θ(chunk) dispatches and Θ(chunk·M·L) HBM traffic that this
     ablation exists to exhibit. Not jitted here; the session runner owns jit.
     """
     M, L = bid.shape
-    m_padded = pad_to_multiple(M, mb)
+    m_padded, rows, params, st0 = _chunk_operands(
+        bid, ask, last, pmid, ext_buy, ext_ask, mb=mb, params=params,
+        market_ids=market_ids, peer_mid=peer_mid, stats=stats,
+        stats_only=stats_only)
+    mids, bid, ask, last, pmid, ext_buy, ext_ask, peer_mid = rows
     grid = (m_padded // mb,)
-
-    if market_ids is None:
-        market_ids = jnp.arange(M, dtype=jnp.int32)
-    mids = jnp.reshape(jnp.asarray(market_ids, dtype=jnp.int32), (M, 1))
-    if m_padded != M:
-        pad_ids = jnp.arange(M, m_padded, dtype=jnp.int32)[:, None]
-        mids = jnp.concatenate([mids, pad_ids], axis=0)
-    params = resolve_params(cfg, M, params, jnp)
-    if peer_mid is None:
-        # Chunk-entry coupling freeze over local rows (single-device case);
-        # sharded callers pass the halo-exchanged column explicitly.
-        peer_mid = resolve_peer_mids(pmid, params.coupling_peer, jnp)
-    bid, ask, last, pmid, ext_buy, ext_ask, peer_mid = (
-        _pad_rows(x, m_padded) for x in (bid, ask, last, pmid, ext_buy,
-                                         ext_ask, peer_mid))
-    params = pad_params(params, m_padded)
 
     book_spec = pl.BlockSpec((mb, L), lambda i: (i, 0))
     scalar_spec = pl.BlockSpec((mb, 1), lambda i: (i, 0))
@@ -233,15 +144,6 @@ def naive_clearing_chunk(
     step0_s = step0[0, 0]
     n_valid_s = n_valid[0, 0]
     zeros_ext = jnp.zeros_like(ext_buy)
-
-    if stats_only and stats is None:
-        raise ValueError("stats_only=True requires the carried `stats` "
-                         "accumulators (see repro.core.stats.init_stats)")
-    st0 = None
-    if stats_only:
-        st0 = stats_mod.MarketStats(
-            *(_pad_rows(jnp.asarray(x, dtype=jnp.float32), m_padded)
-              for x in stats))
 
     def host_step(carry, s):
         if stats_only:

@@ -5,10 +5,12 @@ On a TPU backend the kernels lower through Mosaic; on any other backend
 body runs as plain JAX ops with the same bits. The runner decides from
 ``jax.default_backend()``; there is no option to force either mode.
 
-Both Pallas families are plumbed through the Session API: the chunk entries
-(:func:`repro.kernels.kinetic_clearing.kinetic_clearing_chunk`,
-:func:`repro.kernels.naive_clearing.naive_clearing_chunk`) take runtime
-``(step0, n_valid)`` scalars plus the per-market
+Each Pallas family has one entry, and this runner is the program's only
+caller of either:
+:func:`repro.kernels.kinetic_clearing.kinetic_clearing_chunk` (the
+``pallas-kinetic`` backend) and
+:func:`repro.kernels.naive_clearing.naive_clearing_chunk` (``pallas-naive``).
+Both take runtime ``(step0, n_valid)`` scalars plus the per-market
 :class:`repro.core.params.MarketParams` operands over a static chunk
 length, so one trace serves any requested step count *and any scenario
 mixture*; the runner jits them with donated state buffers (params are

@@ -12,6 +12,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.core import params as params_mod
 from repro.core.config import MarketConfig
 from repro.core.result import SimResult
 from repro.core.step import initial_state, simulate_step
@@ -24,7 +25,9 @@ def _run(bid, ask, last, pmid, *, cfg: MarketConfig, scan: str):
     market_ids = jnp.arange(cfg.num_markets, dtype=jnp.int32)[:, None]
 
     def step(state, s):
-        new_state, out = simulate_step(cfg, state, s, market_ids, jnp, scan=scan)
+        new_state, out = simulate_step(
+            cfg, state, s, market_ids, jnp, scan=scan,
+            params=params_mod.scalar_params(cfg, jnp))
         return new_state, (out.price[:, 0], out.volume[:, 0])
 
     state0 = MarketState(bid=bid, ask=ask, last_price=last, prev_mid=pmid)
@@ -52,7 +55,8 @@ def _run_sequential(bid, ask, last, pmid, *, cfg: MarketConfig):
 
     def step(state, s):
         new_state, out = simulate_step_sequential(
-            cfg, state, s, market_ids, jnp)
+            cfg, state, s, market_ids, jnp,
+            params=params_mod.scalar_params(cfg, jnp))
         return new_state, (out.price[:, 0], out.volume[:, 0])
 
     state0 = MarketState(bid=bid, ask=ask, last_price=last, prev_mid=pmid)

@@ -1,6 +1,6 @@
 """JAX's persistent compilation cache for the program's entry points.
 
-Entry points (``chip_smoke.py``, ``benchmarks/run.py``) call
+Entry points (``chip_smoke.py``, ``kbench/run.py``) call
 :func:`enable_compile_cache` before their first compile; importing the
 library and running the tests never do. Where ``JAX_COMPILATION_CACHE_DIR``
 is set, JAX reads it itself and nothing is set here. Otherwise the cache

@@ -36,13 +36,3 @@ def make_markets_mesh(devices=None, skip=()):
             "forces N host devices on CPU)")
     return jax.make_mesh((n,), ("markets",), devices=avail[:n],
                          axis_types=(jax.sharding.AxisType.Auto,))
-
-
-# TPU v5e hardware model used by the roofline analysis (EXPERIMENTS.md §Roofline)
-HW = {
-    "peak_flops_bf16": 197e12,   # per chip
-    "hbm_bw": 819e9,             # bytes/s per chip
-    "ici_link_bw": 50e9,         # bytes/s per link per direction
-    "ici_links_per_axis": 2,     # 2D torus: 2 links per mesh axis
-    "hbm_per_chip": 16e9,
-}

@@ -1,6 +1,5 @@
 """Order binning: one-hot MXU contraction vs scatter reference (bitwise),
-plus tile-selection edge cases (legacy ``pick_tile`` divisors and the
-padded ``auto_tile`` policy that replaced them for the session entries).
+plus tile-selection edge cases of the padded ``auto_tile`` policy.
 
 The one-hot contraction is the TPU-native replacement for the paper's
 shared-memory atomicAdd histogram; because quantities are exact small
@@ -13,7 +12,6 @@ import pytest
 from repro.core.step import bin_orders_onehot
 from repro.kernels.autotune import (auto_tile, candidate_tiles,
                                     default_agent_chunk, pad_to_multiple)
-from repro.kernels.kinetic_clearing import pick_tile
 
 
 def _bin_orders_scatter_ref(side_buy, price, qty, M, L):
@@ -159,36 +157,12 @@ def test_onehot_mass_conservation():
     assert (buy.sum(axis=1) + sell.sum(axis=1) == qty.sum(axis=1)).all()
 
 
-class TestPickTile:
-    def test_divisor_and_bound(self):
-        for m in range(1, 300):
-            mb = pick_tile(m)
-            assert 1 <= mb <= min(8, m)
-            assert m % mb == 0
-
-    def test_prime_m_degenerates_to_one(self):
-        # A prime M > target has no divisor <= target except 1.
-        for m in (11, 13, 8191):
-            assert pick_tile(m) == 1
-
-    def test_m_smaller_than_target(self):
-        # M <= target: the whole ensemble is one tile.
-        for m in (1, 2, 3, 5, 7, 8):
-            assert pick_tile(m) == m
-        assert pick_tile(3, target=8) == 3
-
-    def test_custom_target(self):
-        assert pick_tile(64, target=16) == 16
-        assert pick_tile(24, target=16) == 12
-        assert pick_tile(17, target=16) == 1
-
-
 class TestAutoTile:
     """The padded tile policy: prime/odd M must never degrade to MB=1."""
 
     def test_prime_matches_even_tile_shape(self):
-        # The seed's pick_tile pathology: M=63 ran MB=1. The padded policy
-        # must give M=63 the exact tile shape (and grid) of M=64.
+        # A divisor policy would run M=63 at MB=1. The padded policy must
+        # give M=63 the exact tile shape (and grid) of M=64.
         assert auto_tile(63) == auto_tile(64)
         assert auto_tile(63).mb == 8
         assert auto_tile(63).m_padded == 64

@@ -1,25 +1,43 @@
-"""Per-kernel shape/dtype sweeps against the pure-jnp oracle (bitwise)."""
+"""Per-kernel shape/dtype sweeps against the pure-jnp oracle (bitwise).
+
+Each kernel is reached through its one entry, the chunk entry the session
+runner calls, run for a whole horizon in one chunk (``chunk = n_valid = S``)
+with zero external orders and the config's parameters broadcast over the
+markets.
+"""
 import dataclasses
+import functools
 
 import numpy as np
 import pytest
 
 from repro.core.config import MarketConfig
+from repro.core.params import params_from_config
 from repro.core.step import initial_state
 from repro.kernels import ref
-from repro.kernels.kinetic_clearing import kinetic_clearing, pick_tile
-from repro.kernels.naive_clearing import naive_clearing
+from repro.kernels.autotune import auto_tile, pad_to_multiple
+from repro.kernels.kinetic_clearing import kinetic_clearing_chunk
+from repro.kernels.naive_clearing import naive_clearing_chunk
 
 FIELDS = ("bid", "ask", "last_price", "prev_mid", "price_path", "volume_path")
+KERNELS = {"kinetic": kinetic_clearing_chunk, "naive": naive_clearing_chunk}
 
 
 def _run_kernel(kernel_fn, cfg, mb):
+    import jax
     import jax.numpy as jnp
 
+    S = cfg.num_steps
     state = initial_state(cfg, jnp)
-    out = kernel_fn(state.bid, state.ask, state.last_price, state.prev_mid,
-                    cfg=cfg, mb=mb, interpret=True)
-    return tuple(np.asarray(o) for o in out)
+    horizon = jnp.full((1, 1), S, jnp.int32)
+    zero_orders = jnp.zeros_like(state.bid)
+    run = jax.jit(functools.partial(kernel_fn, cfg=cfg, chunk=S, mb=mb,
+                                    interpret=True))
+    out = run(state.bid, state.ask, state.last_price, state.prev_mid,
+              jnp.zeros((1, 1), jnp.int32), horizon, zero_orders,
+              zero_orders, params=params_from_config(cfg))
+    # The mid path has no counterpart in the oracle's result.
+    return tuple(np.asarray(o) for o in out[:len(FIELDS)])
 
 
 @pytest.mark.parametrize("M,A,L,S", [
@@ -35,8 +53,7 @@ def test_kernel_shape_sweep(M, A, L, S, kernel):
     cfg = MarketConfig(num_markets=M, num_agents=A, num_levels=L,
                        num_steps=S, seed=M * 1000 + A)
     oracle = ref.simulate_reference(cfg).to_numpy()
-    fn = kinetic_clearing if kernel == "kinetic" else naive_clearing
-    out = _run_kernel(fn, cfg, mb=pick_tile(M))
+    out = _run_kernel(KERNELS[kernel], cfg, mb=auto_tile(M).mb)
     for f, got in zip(FIELDS, out):
         want = np.asarray(getattr(oracle, f))
         assert got.shape == want.shape, f
@@ -48,7 +65,7 @@ def test_kinetic_tile_sweep(mb):
     cfg = MarketConfig(num_markets=8, num_agents=32, num_levels=32,
                        num_steps=6, seed=5)
     oracle = ref.simulate_reference(cfg).to_numpy()
-    out = _run_kernel(kinetic_clearing, cfg, mb=mb)
+    out = _run_kernel(kinetic_clearing_chunk, cfg, mb=mb)
     for f, got in zip(FIELDS, out):
         assert (got == np.asarray(getattr(oracle, f))).all()
 
@@ -59,7 +76,7 @@ def test_kinetic_scan_modes(scan):
     cfg = MarketConfig(num_markets=8, num_agents=64, num_levels=128,
                        num_steps=8, seed=9)
     oracle = ref.simulate_reference(cfg, scan=scan).to_numpy()
-    out = _run_kernel(kinetic_clearing, cfg, mb=4)
+    out = _run_kernel(kinetic_clearing_chunk, cfg, mb=4)
     for f, got in zip(FIELDS, out):
         assert (got == np.asarray(getattr(oracle, f))).all()
 
@@ -70,7 +87,7 @@ def test_population_mix_sweep():
         cfg = MarketConfig(num_markets=4, num_agents=40, num_levels=32,
                            num_steps=10, alpha_momentum=amom, seed=3)
         oracle = ref.simulate_reference(cfg).to_numpy()
-        out = _run_kernel(kinetic_clearing, cfg, mb=4)
+        out = _run_kernel(kinetic_clearing_chunk, cfg, mb=4)
         for f, got in zip(FIELDS, out):
             assert (got == np.asarray(getattr(oracle, f))).all()
 
@@ -83,13 +100,6 @@ def test_volume_bounded_by_mantissa():
     r = ref.simulate_reference(cfg).to_numpy()
     assert r.bid.max() < 2**24 / 1024
     assert r.ask.max() < 2**24 / 1024
-
-
-def test_pick_tile():
-    assert pick_tile(16384) == 8
-    assert pick_tile(6) == 6
-    assert pick_tile(7) == 7
-    assert pick_tile(12, target=8) == 6
 
 
 @pytest.mark.parametrize("kernel", ["kinetic", "naive"])
@@ -107,31 +117,32 @@ def test_interpret_mode_refused_on_tpu(kernel, monkeypatch):
     assert open_runner(cfg, 2, mb=8).interpret  # CPU: interpret mode
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert not open_runner(cfg, 2, mb=8).interpret
-    fn = kinetic_clearing if kernel == "kinetic" else naive_clearing
     with pytest.raises(ValueError, match="interpret=True on a TPU"):
-        _run_kernel(fn, cfg, mb=8)
+        _run_kernel(KERNELS[kernel], cfg, mb=8)
 
 
+@pytest.mark.parametrize("num_markets", [63, 13, 7, 3, 1])
 @pytest.mark.parametrize("kernel", ["pallas-kinetic", "pallas-naive"])
-def test_padded_tile_prime_m_regression(kernel):
-    """pick_tile pathology regression: M=63 must run the *same* padded tile
-    shape as M=64 (MB=8, 8 grid cells) instead of degrading to MB=1, and the
-    padded run must stay bitwise-identical to the unpadded oracle."""
+def test_padded_tile_prime_m_regression(kernel, num_markets):
+    """A prime, odd or tiny M runs full MB=8 tiles over a market axis padded
+    to a multiple of 8 (M=63 the *same* tile shape as M=64, 8 grid cells),
+    never a tile that divides M, and the padded run stays bitwise-identical
+    to the unpadded oracle."""
     from repro.core.session import Engine
 
     eng = Engine(kernel)
-    cfg63 = MarketConfig(num_markets=63, num_agents=16, num_levels=32,
-                         num_steps=6, seed=11)
-    cfg64 = dataclasses.replace(cfg63, num_markets=64)
-    r63 = eng._runner(cfg63, 6)
-    r64 = eng._runner(cfg64, 6)
-    assert r63.tile.mb == 8 and r63.tile.m_padded == 64
-    assert (r63.tile.mb, r63.tile.m_padded) == (r64.tile.mb,
-                                                r64.tile.m_padded)
+    cfg = MarketConfig(num_markets=num_markets, num_agents=16, num_levels=32,
+                       num_steps=6, seed=11)
+    tile = eng._runner(cfg, 6).tile
+    assert tile.mb == 8
+    assert tile.m_padded == pad_to_multiple(num_markets, 8)
+    if num_markets == 63:
+        tile64 = eng._runner(dataclasses.replace(cfg, num_markets=64),
+                             6).tile
+        assert (tile.mb, tile.m_padded) == (tile64.mb, tile64.m_padded)
 
-    oracle = ref.simulate_reference(cfg63).to_numpy()
-    got = eng.open(cfg63).run_to_result(6).to_numpy()
-    for f in ("bid", "ask", "last_price", "prev_mid", "price_path",
-              "volume_path"):
+    oracle = ref.simulate_reference(cfg).to_numpy()
+    got = eng.open(cfg).run_to_result(6).to_numpy()
+    for f in FIELDS:
         assert (np.asarray(getattr(got, f))
                 == np.asarray(getattr(oracle, f))).all(), f

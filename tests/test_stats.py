@@ -162,6 +162,7 @@ def test_stats_only_rejected_by_oneshot_wrappers():
 
 
 def test_stats_only_rejected_without_accumulators():
+    from repro.core.params import MarketParams
     from repro.kernels.kinetic_clearing import kinetic_clearing_chunk
     import jax.numpy as jnp
 
@@ -170,4 +171,5 @@ def test_stats_only_rejected_without_accumulators():
     i = jnp.zeros((1, 1), jnp.int32)
     with pytest.raises(ValueError, match="stats_only"):
         kinetic_clearing_chunk(z, z, s, s, i, i, z, z, cfg=CFG, chunk=4,
+                               params=MarketParams.zeros(8, jnp),
                                stats_only=True, interpret=True)
